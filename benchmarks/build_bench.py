@@ -55,7 +55,7 @@ def phase_split_rows(
 ) -> list:
     """Host-driver build with find/commit timed separately, one row per
     (commit backend, commit tile).  Sizes stay small: the pallas commit is
-    interpret-mode off-TPU.  ``profile`` is a benchmarks.common.PROFILES
+    interpret-mode on the CPU backend.  ``profile`` is a benchmarks.common.PROFILES
     name (resolved to its underlying norm-distribution shape at a
     phase-split-sized N).  ``backends``/``tiles`` restrict the matrix (the
     bench-smoke test uses both); by default every commit backend runs, the

@@ -28,9 +28,9 @@ def main():
     index = build_sharded(items, shards, plus=True, build_backend="scan",
                           max_degree=16, ef_construction=32, insert_batch=512)
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((shards,), ("model",))
+    mesh = make_mesh((shards,), ("model",))
     print(f"mesh: {mesh}")
 
     ids, scores, evals = sharded_search(index, queries, mesh=mesh, k=k, ef=40)

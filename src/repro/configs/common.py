@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.launch.mesh import batch_axes_of, data_parallelism
 from repro.models import gnn as gnn_mod

@@ -17,12 +17,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.similarity import pair_scores
-
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _exact_topk_block(queries: jax.Array, items: jax.Array, k: int):
-    scores = pair_scores(queries, items)
+    # Full fp32 products: the default fp32 dot on TPU rounds its inputs to
+    # bf16, which would make the ground truth itself approximate.
+    scores = jnp.einsum(
+        "bd,nd->bn", queries, items, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     vals, idxs = jax.lax.top_k(scores, k)
     return vals, idxs.astype(jnp.int32)
 

@@ -19,7 +19,7 @@ are committed functionally:
                                   every touched row is gathered, rescored,
                                   deduped and re-ranked on-chip, with
                                   ``commit_tile`` targets merged per grid
-                                  step (interpret mode off-TPU)
+                                  step (interpret mode on the CPU backend)
 
 ``commit_tile`` sizes the fused commit kernel's grid tiles ("auto" resolves
 via the norm-skew planner, kernels/commit_merge/ops.resolve_commit_tile);

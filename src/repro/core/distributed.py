@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.graph import GraphIndex
 from repro.core.search import beam_search
@@ -82,6 +82,8 @@ ROUTE_MODES = ("none", "upper_bound")
 # The sharded path accepts one storage value beyond STORAGE_BACKENDS:
 # "tiered" = f32 on the hottest (max ``max_norm``) shard, int8 elsewhere.
 SHARD_STORAGE = ("f32", "int8", "tiered")
+# Mesh axis the shards are laid out along (one shard per device).
+SHARD_AXIS = "model"
 
 
 def validate_partition(partition: str) -> None:
@@ -243,10 +245,19 @@ def build_sharded(
     build_backend: str = "host",
     storage: str = "f32",
     partition: str = "roundrobin",
+    mesh: Optional[Mesh] = None,
     **index_kwargs,
 ) -> ShardedIndex:
     """Split ``items`` into ``n_shards`` row shards and build one local
     index per shard.
+
+    With ``mesh`` given, shard s lives on device s of the mesh's
+    ``SHARD_AXIS`` (``NamedSharding(mesh, P(SHARD_AXIS))`` on every leaf,
+    ``n_shards`` must equal the axis size) and the scan build runs under
+    ``shard_map``: every device builds its own shard's graphs from its own
+    rows.  Without it the
+    stacked index stays on the default device (the single-device oracle
+    layout the CPU tests use).
 
     ``partition="roundrobin"`` keeps the legacy contiguous uniform split;
     ``"norm_bands"`` sorts the catalog by ||x|| and cuts count-balanced
@@ -273,9 +284,14 @@ def build_sharded(
 
     _validate_shard_storage(storage)
     validate_partition(partition)
+    if mesh is not None and mesh.shape[SHARD_AXIS] != n_shards:
+        raise ValueError(
+            f"mesh axis {SHARD_AXIS!r} has {mesh.shape[SHARD_AXIS]} devices; "
+            f"placing one shard per device needs {n_shards}")
     n = items.shape[0]
     per = -(-n // n_shards)
-    norms_np = np.linalg.norm(np.asarray(items, np.float32), axis=-1)
+    items = np.asarray(items, np.float32)
+    norms_np = np.linalg.norm(items, axis=-1)
     if partition == "norm_bands":
         bands, band_max = norm_band_partition(norms_np, n_shards)
     else:
@@ -290,23 +306,23 @@ def build_sharded(
     counts = [len(b) for b in bands]
     gids = bands if partition == "norm_bands" else None
 
-    locals_ = []
-    for rows in bands:
-        local = jnp.asarray(np.asarray(items)[rows])
-        if local.shape[0] < per:  # pad the ragged tail shard with zeros
-            pad = per - local.shape[0]
-            local = jnp.concatenate(
-                [local, jnp.zeros((pad, items.shape[-1]), items.dtype)]
-            )
-        locals_.append(local)
+    # Host-side [P, Nloc, d] stack (the ragged tail shard zero padded), so
+    # the shards reach their devices without passing through one of them.
+    stacked = np.zeros((n_shards, per, items.shape[-1]), np.float32)
+    for s, rows in enumerate(bands):
+        stacked[s, : len(rows)] = items[rows]
+    place = (lambda x: x) if mesh is None else functools.partial(
+        jax.device_put, device=NamedSharding(mesh, P(SHARD_AXIS)))
 
     if build_backend == "scan":
-        index = _build_sharded_scan(locals_, counts, plus=plus, **index_kwargs)
+        index = _build_sharded_scan(
+            place(stacked), counts, plus=plus, mesh=mesh, **index_kwargs)
         index = index._replace(
             gid=_pad_gids(gids, per) if gids is not None else None,
             max_norm=jnp.asarray(band_max),
         )
-        return _attach_stores(index, storage)
+        return place(_attach_stores(index, storage))
+    locals_ = [jnp.asarray(x) for x in stacked]
 
     ip_graphs, ang_graphs = [], []
     for local in locals_:
@@ -321,7 +337,7 @@ def build_sharded(
         ip_graphs, ang_graphs if plus else None, counts,
         gids=gids, max_norms=band_max,
     )
-    return _attach_stores(index, storage)
+    return place(_attach_stores(index, storage))
 
 
 def _pad_gids(gids: Sequence[np.ndarray], nloc: int) -> jax.Array:
@@ -349,13 +365,16 @@ def _attach_stores(index: ShardedIndex, storage: str) -> ShardedIndex:
 
 
 def _build_sharded_scan(
-    locals_: Sequence[jax.Array],
+    stacked: jax.Array,       # [P, Nloc, d]
     counts: Sequence[int],
     *,
     plus: bool,
+    mesh: Optional[Mesh] = None,
     **index_kwargs,
 ) -> ShardedIndex:
-    """Shard-parallel scan build: one jit, vmap over the shard axis."""
+    """Shard-parallel scan build: one jit over the shard axis — vmapped on
+    the default device, or shard_mapped with one shard per device of
+    ``mesh``."""
     from repro.core.build import (
         batch_schedule, resolve_commit_tile, scan_build_arrays,
     )
@@ -365,9 +384,8 @@ def _build_sharded_scan(
 
     proto = (IpNSWPlus if plus else IpNSW)(**index_kwargs)
 
-    p = len(locals_)
-    per = int(locals_[0].shape[0])
-    stacked = jnp.stack(locals_)                      # [P, Nloc, d]
+    stacked = jnp.asarray(stacked)
+    p, per = int(stacked.shape[0]), int(stacked.shape[1])
     norms = jnp.linalg.norm(stacked, axis=-1)         # [P, Nloc]
     # Static tile for every shard's commits, resolved before the vmap trace
     # (inside it the norms are abstract and "auto" could not use the skew).
@@ -381,9 +399,22 @@ def _build_sharded_scan(
     offsets = jnp.asarray([s * per for s in range(p)], jnp.int32)
     count = jnp.asarray(list(counts), jnp.int32)
 
+    def per_shard(fn):
+        """Map ``fn`` (one shard's arrays -> its graph arrays) over the
+        leading shard axis: vmap on one device, shard_map across ``mesh``."""
+        if mesh is None:
+            return jax.jit(jax.vmap(fn))
+
+        def local(*blocks):  # each device holds a [1, ...] block
+            out = fn(*(x[0] for x in blocks))
+            return jax.tree.map(lambda x: x[None], out)
+
+        return jax.jit(shard_map(local, mesh=mesh, in_specs=P(SHARD_AXIS),
+                                 out_specs=P(SHARD_AXIS), check_vma=False))
+
     if plus:
         ang_items = normalize(stacked)
-        ang_norms = jnp.ones((p, per), jnp.float32)
+        ang_norms = jnp.ones_like(norms)
         fn = functools.partial(
             scan_build_plus_arrays,
             max_degree=proto.max_degree,
@@ -398,8 +429,8 @@ def _build_sharded_scan(
             commit_tile=commit_tile,
         )
         (a_adj, a_size, a_entry, a_enorm,
-         i_adj, i_size, i_entry, i_enorm) = jax.jit(
-            jax.vmap(lambda it, ai, no, an: fn(it, ai, no, an, bids, valid))
+         i_adj, i_size, i_entry, i_enorm) = per_shard(
+            lambda it, ai, no, an: fn(it, ai, no, an, bids, valid)
         )(stacked, ang_items, norms, ang_norms)
         ip = GraphIndex(adj=i_adj, items=stacked, size=i_size, entry=i_entry,
                         entry_norm=i_enorm)
@@ -418,8 +449,8 @@ def _build_sharded_scan(
         commit_backend=proto.commit_backend,
         commit_tile=commit_tile,
     )
-    adj, size, entry, enorm = jax.jit(
-        jax.vmap(lambda it, no: fn(it, no, bids, valid))
+    adj, size, entry, enorm = per_shard(
+        lambda it, no: fn(it, no, bids, valid)
     )(stacked, norms)
     ip = GraphIndex(adj=adj, items=stacked, size=size, entry=entry,
                     entry_norm=enorm)
@@ -589,12 +620,17 @@ def _require_route_index(index: ShardedIndex, route: str, storage: str):
         )
 
 
+_SEARCH_STATIC = ("k", "ef", "max_steps", "plus", "backend", "ang_ef",
+                  "k_angular", "storage", "route", "return_stats")
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis") + _SEARCH_STATIC)
 def sharded_search(
     index: ShardedIndex,
     queries: jax.Array,
     *,
     mesh: Mesh,
-    axis: str = "model",
+    axis: str = SHARD_AXIS,
     k: int = 10,
     ef: int = 64,
     max_steps: Optional[int] = None,
@@ -608,6 +644,8 @@ def sharded_search(
     return_stats: bool = False,
 ):
     """shard_map driver: local walk on every shard + all-gather top-k merge.
+    Jitted (both drivers are): every keyword but ``shard_mask`` is static, so
+    one program is compiled per configuration and shapes, and reused.
 
     Queries are replicated over ``axis`` (shard the batch over the remaining
     mesh axes with in_shardings at the jit level).  ``backend`` selects the
@@ -747,6 +785,7 @@ def sharded_search(
     return out[:3]
 
 
+@functools.partial(jax.jit, static_argnames=_SEARCH_STATIC)
 def sharded_search_reference(
     index: ShardedIndex,
     queries: jax.Array,
@@ -764,8 +803,8 @@ def sharded_search_reference(
     return_stats: bool = False,
 ):
     """Single-device oracle: identical math to ``sharded_search`` with the
-    shard dimension mapped by vmap instead of shard_map.  Used by tests to
-    pin down the distributed semantics on CPU.
+    shard dimension mapped by ``lax.map`` instead of shard_map.  Used by
+    tests to pin down the distributed semantics on CPU.
 
     With ``route="upper_bound"`` this path DEFINES the routing semantics:
     an unrolled sequential pass over the shards in descending ``max_norm``
@@ -796,7 +835,10 @@ def sharded_search_reference(
             gids, scores = _globalize(blk, ids, scores)
             return gids, scores, evals
 
-        all_ids, all_scores, all_evals = jax.vmap(one)(index)
+        # lax.map, not vmap: each shard walks as the same unbatched program
+        # a device runs under shard_map (a vmapped pallas walk has no TPU
+        # lowering for its whole-array ANY-memory operands).
+        all_ids, all_scores, all_evals = jax.lax.map(one, index)
         out_ids, out_scores = _merge_topk(all_ids, all_scores, k, shard_mask)
         if return_stats:
             mask = shard_mask if shard_mask is not None else jnp.ones(

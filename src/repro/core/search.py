@@ -22,9 +22,9 @@ Step backends (``backend=``, see DESIGN.md):
   "reference" — the loop body is ``beam_step_ref``: ~6 separate XLA ops with
                 HBM round-trips between gather, score, mask and merge.
   "pallas"    — the loop body is the fused ``beam_step`` kernel: the whole
-                iteration runs per query tile in VMEM.  Off-TPU the kernel
-                auto-falls back to interpret mode (bit-identical ids, CPU
-                speed), so the same code path is testable everywhere.
+                iteration runs per query tile in VMEM.  On the CPU backend
+                the kernel runs in Pallas interpret mode (bit-identical
+                ids), so the same code path is testable without a chip.
 Both backends share seeding/termination and return identical result ids.
 
 Storage backends (``storage=``, see DESIGN.md §8): with ``storage="int8"``
@@ -112,7 +112,8 @@ def make_step_fn(
 
     This is the extension point every walk kernel slots into — later fused
     kernels (distance pruning, batched build) register the same shape.
-    ``interpret=None`` auto-falls back to Pallas interpret mode off-TPU.
+    ``interpret=None`` interprets on the CPU backend only
+    (kernels/common.resolve_interpret).
     With ``store`` given (the int8 storage backend), steps score against the
     quantized codes instead of ``items`` — via ``quant_score_ref`` on the
     reference path and the kernel's int8 row-gather path on pallas.
@@ -122,7 +123,7 @@ def make_step_fn(
     """
     # Deferred import: kernels.beam_step.ref reuses core.similarity, so a
     # module-level import here would be circular through core/__init__.
-    from repro.kernels.beam_step import beam_step, beam_step_ref
+    from repro.kernels.beam_step import beam_step_ref
 
     if backend == "reference":
         step_score_fn = score_fn if store is None else _store_score_fn(store)
@@ -142,30 +143,22 @@ def make_step_fn(
                 "product and cannot honor a custom score_fn; use "
                 "backend='reference' for custom similarities"
             )
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        # Pre-pad once, outside the while_loop, so the per-step pads inside
-        # the jit'd kernel wrapper fold away (zero-padding keeps fp32 inner
-        # products bit-identical).  _round_up is the kernel wrapper's own
-        # lane-width rule, so the two stay in lockstep.
-        from repro.kernels.beam_step.ops import _round_up
+        # Lay the graph out for the kernel's DMAs once, outside the
+        # while_loop (kernels/common.py; zero-padding keeps fp32 inner
+        # products bit-identical).
+        from repro.kernels.beam_step.ops import beam_step_on, prepare_walk
 
-        d = items.shape[1]
-        dp = _round_up(d, 128)
-        q_pad = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, dp - d)))
         if store is None:
-            x_pad = jnp.pad(items.astype(jnp.float32), ((0, 0), (0, dp - d)))
-            scales = None
+            q_pad, ops = prepare_walk(queries, adj, items, live=live)
         else:
-            x_pad = jnp.pad(store.codes.astype(jnp.int8), ((0, 0), (0, dp - d)))
-            scales = store.scales
-
-        live_col = None if live is None else live.astype(jnp.int32)
+            q_pad, ops = prepare_walk(queries, adj, store.codes,
+                                      store.scales, live)
+        degree = adj.shape[1]
 
         def step_fn(pool_ids, pool_scores, pool_checked, visited, done):
-            return beam_step(
+            return beam_step_on(
                 pool_ids, pool_scores, pool_checked, visited, done,
-                q_pad, adj, x_pad, scales, live_col, interpret=interpret,
+                q_pad, ops, degree=degree, interpret=interpret,
             )
 
         return step_fn

@@ -1,5 +1,5 @@
-"""Pallas TPU kernels for the MIPS hot spots (validated with interpret=True
-on CPU; TPU is the compile target).
+"""Pallas TPU kernels for the MIPS hot spots: compiled by Mosaic on the TPU,
+run in interpret mode on the CPU backend (``common.resolve_interpret``).
 
   mips_topk    — tiled exact-MIPS linear scan + streaming top-k (MXU)
   gather_score — scalar-prefetch fused row-gather + dot (beam expansion)

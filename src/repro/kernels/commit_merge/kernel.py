@@ -10,9 +10,9 @@ stages.  Here the wrapper (``ops.py``) buckets only the ``E`` proposals to
 target rows with ONE E-row sort, packs ``T`` rows per grid step, and each
 step finishes its tile of touched rows on-chip:
 
-  1. DMA each live target's adjacency row HBM->SMEM (scalar ids for the
-     gather loop) and HBM->VMEM (vector lanes), and each target's item
-     vector HBM->VMEM — T targets' copies all started before any wait;
+  1. DMA each live target's packed adjacency record HBM->SMEM (its ids are
+     read as scalars) and its item row HBM->VMEM — T targets' copies all
+     started before any wait (layouts: kernels/common.py);
   2. DMA the tile's T·M existing-neighbor item rows HBM->VMEM (same
      explicit-DMA idiom as ``beam_step``: the ids are read from the rows
      *inside* the kernel, so a scalar-prefetch BlockSpec cannot express
@@ -33,20 +33,25 @@ live, and its dead rows fetch (and then fully mask) row 0.
 ``T = 1`` degenerates to the original one-target-per-step layout, which is
 how the pre-tiling grid remains expressible (and tested).
 
-VMEM budget per step: T·(M+1)·dp·4 (target + neighbor rows) + T·(2K + 3M)
-words — ~105 KB for T=8, M=16, dp=128, K=512 (~140 KB counting the tile's
-bucket input blocks); far under the ~16 MB/core limit.
+Every per-tile block is the trailing ``(T, x)`` of a 3-D array, so any
+tile size is aligned for Mosaic.  VMEM budget per step: T·(M+1)·dp·4
+(target + neighbor rows) + T·(2K + 3M) words — ~1 MB for T=32, M=16,
+dp=384, K=256; under the 16 MB scoped-VMEM default.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import LANES, resolve_interpret, slots_per_node
+
 NEG_INF = float("-inf")
+_LANE_SHIFT = LANES.bit_length() - 1
 
 
 def ranked_top_m(ids, scores, valid, m: int):
@@ -77,69 +82,67 @@ def ranked_top_m(ids, scores, valid, m: int):
 
 def _commit_merge_kernel(
     tgt_ref, bi_ref, bs_ref,          # VMEM-blocked inputs (one target tile)
-    adj_hbm, items_hbm,               # whole arrays, ANY/HBM
+    adj_hbm, rows_hbm,                # whole arrays, ANY/HBM
     out_ref,                          # [T, M] new row ids
-    adj_smem, adj_vmem, tvec_ref, rows_ref, sems,
+    adj_smem, tvec_ref, rows_ref, sems,
     *,
     m: int,
     t: int,
 ):
+    mp = slots_per_node(m)
     tgt = tgt_ref[...]                                # [T, 1]
     live = tgt >= 0                                   # [T, 1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+    tis = [jnp.maximum(jnp.max(jnp.where(row == i, tgt, -1)), 0)
+           for i in range(t)]                         # clamped target ids
+    base = [(ti * mp) & (LANES - 1) for ti in tis]   # lane of slot 0
     # The wrapper compacts live targets to a bucket-row prefix, so a tile
     # with a dead first row is entirely pad and skips all DMA (its outputs
     # are fully masked by ``live`` below, so stale/uninitialized scratch
     # contents are never observable).  Dead rows inside the one partially
     # live tile fall through with clamped ids and fetch row 0 harmlessly.
-    live_any = tgt_ref[0, 0] >= 0
+    live_any = jnp.max(jnp.where(row == 0, tgt, -1)) >= 0
 
     @pl.when(live_any)
     def _fetch():
-        # --- 1. adjacency rows (SMEM scalars + VMEM lanes) + target vectors —
+        # --- 1. packed adjacency records (SMEM) + target vectors (VMEM) —
         # all T targets' copies started before any wait, so the fetches
         # overlap on TPU.  ``i`` is a static Python index (T is static).
-        def _adj_s(i):
-            ti = jnp.maximum(tgt_ref[i, 0], 0)
+        def _adj(i):
             return pltpu.make_async_copy(
-                adj_hbm.at[pl.ds(ti, 1), :], adj_smem.at[pl.ds(i, 1), :],
-                sems.at[t * m + i],
-            )
-
-        def _adj_v(i):
-            ti = jnp.maximum(tgt_ref[i, 0], 0)
-            return pltpu.make_async_copy(
-                adj_hbm.at[pl.ds(ti, 1), :], adj_vmem.at[pl.ds(i, 1), :],
-                sems.at[t * m + t + i],
+                adj_hbm.at[pl.ds((tis[i] * mp) >> _LANE_SHIFT, 1)],
+                adj_smem.at[pl.ds(i, 1)], sems.at[0],
             )
 
         def _tv(i):
-            ti = jnp.maximum(tgt_ref[i, 0], 0)
             return pltpu.make_async_copy(
-                items_hbm.at[pl.ds(ti, 1), :], tvec_ref.at[pl.ds(i, 1), :],
-                sems.at[t * m + 2 * t + i],
+                rows_hbm.at[pl.ds(tis[i], 1)], tvec_ref.at[pl.ds(i, 1)],
+                sems.at[1],
             )
 
         for i in range(t):
-            _adj_s(i).start()
-            _adj_v(i).start()
+            _adj(i).start()
             _tv(i).start()
         for i in range(t):
-            _adj_s(i).wait()
+            _adj(i).wait()
 
         # --- 2. gather the T·M existing-neighbor rows (start all, wait all) —
-        # neighbor ids come from the adjacency rows just landed in SMEM; the
-        # flat row index p maps to (tile row p // M, slot p % M).
-        def _row_copy(p):
-            nid = jnp.maximum(adj_smem[p // m, p % m], 0)
+        # neighbor ids come from the adjacency records just landed in SMEM.
+        def _row_copy(i, j):
+            nid = jnp.maximum(adj_smem[i, 0, base[i] + j], 0)
             return pltpu.make_async_copy(
-                items_hbm.at[pl.ds(nid, 1), :], rows_ref.at[pl.ds(p, 1), :],
-                sems.at[p],
+                rows_hbm.at[pl.ds(nid, 1)], rows_ref.at[pl.ds(i * m + j, 1)],
+                sems.at[2],
             )
 
-        jax.lax.fori_loop(0, t * m, lambda p, c: (_row_copy(p).start(), c)[1], 0)
-        jax.lax.fori_loop(0, t * m, lambda p, c: (_row_copy(p).wait(), c)[1], 0)
+        for action in ("start", "wait"):
+            for i in range(t):
+                def body(j, c, i=i):
+                    getattr(_row_copy(i, j), action)()
+                    return c
+
+                jax.lax.fori_loop(0, m, body, 0)
         for i in range(t):
-            _adj_v(i).wait()
             _tv(i).wait()
 
     # --- 3. dedup + rescore — all in VMEM, batched over the T tile rows ----
@@ -147,31 +150,38 @@ def _commit_merge_kernel(
     new_valid = (new_ids >= 0) & live
     new_scores = jnp.where(new_valid, bs_ref[...], NEG_INF)
 
-    ex_ids = adj_vmem[...]                            # [T, M]
-    # existing slot duplicated by a proposal -> dropped (proposal score wins)
-    in_new = (
-        (ex_ids[:, :, None] == new_ids[:, None, :]) & new_valid[:, None, :]
-    ).any(axis=-1)
-    # existing slot repeating an earlier existing slot -> dropped (keep first)
-    eq = ex_ids[:, :, None] == ex_ids[:, None, :]
-    jj = jax.lax.broadcasted_iota(jnp.int32, (t, m, m), 1)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (t, m, m), 2)
-    ex_dup = (eq & (kk < jj)).any(axis=-1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, m), 1)
+    rowm = jax.lax.broadcasted_iota(jnp.int32, (t, m), 0)
+    ex_ids = jnp.zeros((t, m), jnp.int32)             # [T, M]
+    for i in range(t):
+        for j in range(m):
+            ex_ids = jnp.where((rowm == i) & (col == j),
+                               adj_smem[i, 0, base[i] + j], ex_ids)
+    in_new = jnp.zeros((t, m), jnp.bool_)
+    ex_dup = jnp.zeros((t, m), jnp.bool_)
+    for j in range(m):
+        ex_j = ex_ids[:, j:j + 1]
+        # existing slot duplicated by a proposal -> dropped (proposal wins)
+        hit = jnp.max(jnp.where((new_ids == ex_j) & new_valid, 1, 0),
+                      axis=1, keepdims=True) > 0
+        in_new = in_new | ((col == j) & hit)
+        # existing slot repeating an earlier existing slot -> dropped
+        dup = jnp.zeros((t, 1), jnp.bool_)
+        for k in range(j):
+            dup = dup | (ex_ids[:, k:k + 1] == ex_j)
+        ex_dup = ex_dup | ((col == j) & dup)
     ex_valid = (ex_ids >= 0) & live & ~in_new & ~ex_dup
 
-    tvec = tvec_ref[...]                              # [T, dp]
-    rows = rows_ref[...]                              # [T*M, dp]
-    ex_scores = jnp.concatenate(
-        [
-            jax.lax.dot_general(
-                tvec[i : i + 1, :], rows[i * m : (i + 1) * m, :],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for i in range(t)
-        ],
-        axis=0,
-    )                                                 # [T, M]
+    tvec = tvec_ref[...].reshape(t, tvec_ref.shape[-1])   # [T, dp]
+    ex_scores = jnp.zeros((t, m), jnp.float32)
+    for i in range(t):
+        rows = rows_ref[pl.ds(i * m, m)]
+        rows = rows.reshape(m, rows.shape[-1])        # [M, dp]
+        s = jax.lax.dot_general(
+            tvec[i:i + 1, :], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # [1, M]
+        ex_scores = jnp.where(rowm == i, s, ex_scores)
     ex_scores = jnp.where(ex_valid, ex_scores, NEG_INF)
 
     # --- 4. rank and rewrite the tile's rows --------------------------------
@@ -185,48 +195,39 @@ def _commit_merge_kernel(
 
 
 def commit_merge_pallas(
-    utgt: jax.Array,          # [G, 1] int32 unique targets (-1 pad rows,
-    #                           live rows a contiguous prefix)
-    bucket_ids: jax.Array,    # [G, K] int32 deduped proposal ids (-1 padded)
-    bucket_scores: jax.Array, # [G, K] fp32 proposal scores
-    adj: jax.Array,           # [N, M] int32 (-1 padded)
-    items: jax.Array,         # [N, dp] fp32, dp a lane multiple
+    utgt: jax.Array,          # [G/T, T, 1] int32 unique targets (-1 pad
+    #                           rows, live rows a contiguous prefix)
+    bucket_ids: jax.Array,    # [G/T, T, K] int32 deduped proposal ids
+    bucket_scores: jax.Array, # [G/T, T, K] fp32 proposal scores
+    adj: jax.Array,           # [R, 1, 128] packed adjacency (pack_adjacency)
+    rows: jax.Array,          # [N, 1, dp] fp32 item rows (f32_rows)
     *,
-    tile: int = 1,
-    interpret: bool = True,
+    degree: int,
+    interpret: Optional[bool] = None,
 ):
-    """One fused reverse-link merge step per tile of ``tile`` unique targets.
-    ``G`` must be a multiple of ``tile`` (the wrapper pads the bucket table).
-    Returns the ``[G, M]`` rewritten row ids (all ``-1`` for pad rows); the
-    wrapper owns the bucketing pre-pass, the tile padding, and the row
-    scatter."""
-    g = utgt.shape[0]
-    k = bucket_ids.shape[1]
-    m = adj.shape[1]
-    dp = items.shape[1]
-    if g % tile:
-        raise ValueError(f"bucket rows ({g}) must be a multiple of tile ({tile})")
-
-    spec_any = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    """One fused reverse-link merge step per tile of ``T`` unique targets.
+    Every per-tile block is the whole trailing ``(T, x)`` of a 3-D array, so
+    any tile size is aligned for Mosaic.  Returns the ``[G/T, T, M]``
+    rewritten row ids (all ``-1`` for pad rows); the wrapper owns the
+    bucketing pre-pass, the tile padding, and the row scatter."""
+    steps, tile, k = bucket_ids.shape
+    m = degree
+    tile_spec = lambda width: pl.BlockSpec(
+        (None, tile, width), lambda i: (i, 0, 0))
+    spec_any = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
     return pl.pallas_call(
         functools.partial(_commit_merge_kernel, m=m, t=tile),
-        grid=(g // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),   # target ids
-            pl.BlockSpec((tile, k), lambda i: (i, 0)),   # proposal ids
-            pl.BlockSpec((tile, k), lambda i: (i, 0)),   # proposal scores
-            spec_any,                                    # adj (HBM)
-            spec_any,                                    # items (HBM)
-        ],
-        out_specs=pl.BlockSpec((tile, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, m), jnp.int32),
+        grid=(steps,),
+        in_specs=[tile_spec(1), tile_spec(k), tile_spec(k), spec_any,
+                  spec_any],
+        out_specs=tile_spec(m),
+        out_shape=jax.ShapeDtypeStruct((steps, tile, m), jnp.int32),
         scratch_shapes=[
-            pltpu.SMEM((tile, m), jnp.int32),
-            pltpu.VMEM((tile, m), jnp.int32),
-            pltpu.VMEM((tile, dp), jnp.float32),
-            pltpu.VMEM((tile * m, dp), jnp.float32),
-            pltpu.SemaphoreType.DMA((tile * (m + 3),)),
+            pltpu.SMEM((tile, 1, LANES), jnp.int32),
+            pltpu.VMEM((tile, 1, rows.shape[-1]), jnp.float32),
+            pltpu.VMEM((tile * m, 1, rows.shape[-1]), jnp.float32),
+            pltpu.SemaphoreType.DMA((3,)),
         ],
-        interpret=interpret,
-    )(utgt, bucket_ids, bucket_scores, adj, items)
+        interpret=resolve_interpret(interpret),
+    )(utgt, bucket_ids, bucket_scores, adj, rows)

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.commit_merge.kernel import commit_merge_pallas
+from repro.kernels.common import f32_rows, pack_adjacency, round_up
 
 # The planner's trace-time fallback and the skew ladder it climbs: duplicate
 # targets come from hub in-degree, which every profile shows at paper scale
@@ -112,10 +113,6 @@ def resolve_commit_tile(
     return min(t, MAX_COMMIT_TILE)
 
 
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
 @functools.partial(
     jax.jit, static_argnames=("max_cands", "commit_tile", "interpret")
 )
@@ -133,21 +130,15 @@ def commit_merge(
     """Drop-in for commit_merge_ref backed by the fused Pallas kernel.
     ``commit_tile`` targets are merged per grid step (``"auto"`` resolves via
     the planner — pass a pre-resolved int to honor the norm-skew heuristic,
-    see resolve_commit_tile).  ``interpret=None`` auto-falls back to
-    interpret mode off-TPU."""
+    see resolve_commit_tile).  ``interpret=None`` interprets on the CPU
+    backend only (kernels/common.resolve_interpret)."""
     n, m = adj.shape
     e = targets.shape[0]
     if e == 0:
         return adj
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     k = max_cands if max_cands is not None else min(e, n)
     k = max(min(k, e), 1)
     tile = resolve_commit_tile(commit_tile, e=e)
-
-    d = items.shape[-1]
-    dp = _round_up(d, 128)
-    items_pad = jnp.pad(items.astype(jnp.float32), ((0, 0), (0, dp - d)))
 
     # --- bucket the proposals: one stable E-row lex-sort by (target, cand) --
     big = jnp.int32(n + 1)
@@ -173,7 +164,7 @@ def commit_merge(
     # g bucket rows, padded to whole tiles; live targets occupy rows 0..U-1
     # (the sort puts valid keys first), which is the prefix invariant the
     # kernel's per-tile DMA skip relies on.
-    g = _round_up(e, tile)
+    g = round_up(e, tile)
     row = jnp.where(v_b, seg, g)
     col = jnp.where(v_b, pos, 0)
     bucket_ids = (
@@ -189,10 +180,12 @@ def commit_merge(
     )
 
     # --- per-tile VMEM merge + one row-granular scatter back ----------------
+    tiles = lambda x: x.reshape(g // tile, tile, x.shape[-1])
     out_rows = commit_merge_pallas(
-        utgt, bucket_ids, bucket_scores, adj.astype(jnp.int32), items_pad,
-        tile=tile, interpret=interpret,
-    )
+        tiles(utgt), tiles(bucket_ids), tiles(bucket_scores),
+        pack_adjacency(adj), f32_rows(items),
+        degree=m, interpret=interpret,
+    ).reshape(g, m)
     adj_pad = jnp.concatenate([adj, jnp.full((1, m), -1, adj.dtype)], axis=0)
     wrow = jnp.where(utgt[:, 0] >= 0, utgt[:, 0], n)  # pad rows -> dummy row
     return adj_pad.at[wrow].set(out_rows.astype(adj.dtype))[:n]

@@ -22,11 +22,14 @@ q + k + v + out, the flash optimum.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 NEG_BIG = float(-1e30)
 
@@ -89,7 +92,7 @@ def flash_attention_head(
     window=None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     s, hd = q.shape
     t = k.shape[0]
@@ -117,5 +120,5 @@ def flash_attention_head(
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((s, hd), v.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -3,6 +3,7 @@ batch and (kv-head x group) dims — the layout models/layers.py uses."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ def flash_attention(
     window=None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     b, s, h, hd = q.shape
     t, kvh = k.shape[1], k.shape[2]

@@ -16,11 +16,14 @@ invalid slots is the caller's contract (same as similarity.gather_scores).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 
 def _gather_score_kernel(ids_ref, q_ref, x_ref, o_ref, *, bw: int):
@@ -41,7 +44,7 @@ def gather_score_pallas(
     items: jax.Array,
     ids: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """queries [B, d], items [N, d], ids [B, W] int32 in [0, N) ->
     scores [B, W] fp32 where scores[b, w] = queries[b] . items[ids[b, w]]."""
@@ -61,5 +64,5 @@ def gather_score_pallas(
         _gather_score_kernel_rowwise,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, w), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids, queries, items)

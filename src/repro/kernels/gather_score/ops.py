@@ -4,15 +4,13 @@ it as ``score_fn``)."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import round_up
 from repro.kernels.gather_score.kernel import gather_score_pallas
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -21,12 +19,12 @@ def gather_score(
     items: jax.Array,
     ids: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Drop-in for similarity.gather_scores: ids may contain -1 (scored
     against row 0; caller masks)."""
     d = queries.shape[-1]
-    dp = _round_up(d, 128)
+    dp = round_up(d, 128)
     q = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, dp - d)))
     x = jnp.pad(items.astype(jnp.float32), ((0, 0), (0, dp - d)))
     safe = jnp.maximum(ids, 0).astype(jnp.int32)

@@ -20,11 +20,14 @@ uses a masked max instead of take_along_axis (no dynamic gather on TPU VPU).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 NEG_INF = float("-inf")
 
@@ -94,13 +97,14 @@ def mips_topk_pallas(
     scales: "jax.Array | None" = None,
     *,
     k: int,
+    n_items: int,
     bq: int = 128,
     bn: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """queries [B, d], items [N, d] (both pre-padded: B%bq==0, N%bn==0,
-    d%128==0) -> (scores [B, k], ids [B, k]).  ``n_items`` masking of padded
-    item rows is applied inside the kernel via the true N passed by ops.py.
+    d%128==0) -> (scores [B, k], ids [B, k]).  Item rows at or past the true
+    count ``n_items`` are masked inside the kernel.
 
     With ``scales`` ([1, N] fp32, pre-padded like the item rows), ``items``
     holds int8 codes and scores follow the quantized convention
@@ -112,7 +116,7 @@ def mips_topk_pallas(
 
     grid = (b // bq, n // bn)
     kernel = functools.partial(
-        _mips_topk_kernel, k=k, bn=bn, n_items=n, quantized=quantized
+        _mips_topk_kernel, k=k, bn=bn, n_items=n_items, quantized=quantized
     )
     in_specs = [
         pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
@@ -139,5 +143,5 @@ def mips_topk_pallas(
             pltpu.VMEM((bq, k), jnp.int32),
         ],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

@@ -3,17 +3,13 @@ masks padded item rows, strips query padding."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.mips_topk.kernel import _mips_topk_kernel, NEG_INF
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
+from repro.kernels.common import round_up
+from repro.kernels.mips_topk.kernel import mips_topk_pallas
 
 
 @functools.partial(
@@ -27,7 +23,7 @@ def mips_topk(
     k: int = 10,
     bq: int = 128,
     bn: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Exact top-k MIPS.  queries [B, d], items [N, d] (any shapes).
 
@@ -36,10 +32,10 @@ def mips_topk(
     (DESIGN.md §8) — the tile streams 1-byte rows instead of fp32."""
     b, d = queries.shape
     n = items.shape[0]
-    bq = min(bq, _round_up(b, 8))
-    bn = min(bn, _round_up(n, 128))
+    bq = min(bq, round_up(b, 8))
+    bn = min(bn, round_up(n, 128))
 
-    bp, np_, dp = _round_up(b, bq), _round_up(n, bn), _round_up(d, 128)
+    bp, np_, dp = round_up(b, bq), round_up(n, bn), round_up(d, 128)
     q = jnp.pad(queries.astype(jnp.float32), ((0, bp - b), (0, dp - d)))
     if scales is None:
         x = jnp.pad(items.astype(jnp.float32), ((0, np_ - n), (0, dp - d)))
@@ -47,34 +43,7 @@ def mips_topk(
     else:
         x = jnp.pad(items.astype(jnp.int8), ((0, np_ - n), (0, dp - d)))
         scl = jnp.pad(scales.astype(jnp.float32), (0, np_ - n)).reshape(1, np_)
-    grid = (bp // bq, np_ // bn)
-    kernel = functools.partial(
-        _mips_topk_kernel, k=k, bn=bn, n_items=n, quantized=scl is not None
+    scores, ids = mips_topk_pallas(
+        q, x, scl, k=k, n_items=n, bq=bq, bn=bn, interpret=interpret
     )
-    in_specs = [
-        pl.BlockSpec((bq, dp), lambda i, j: (i, 0)),
-        pl.BlockSpec((bn, dp), lambda i, j: (j, 0)),
-    ]
-    operands = [q, x]
-    if scl is not None:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
-        operands.append(scl)
-    scores, ids = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((bq, k), jnp.float32),
-            pltpu.VMEM((bq, k), jnp.int32),
-        ],
-        out_shape=(
-            jax.ShapeDtypeStruct((bp, k), jnp.float32),
-            jax.ShapeDtypeStruct((bp, k), jnp.int32),
-        ),
-        interpret=interpret,
-    )(*operands)
     return scores[:b], ids[:b]

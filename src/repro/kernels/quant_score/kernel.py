@@ -14,10 +14,14 @@ Ids must be pre-clamped to [0, N); -1 masking is the ops.py wrapper's job
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
 
 
 def _quant_score_kernel(ids_ref, q_ref, c_ref, s_ref, o_ref):
@@ -34,7 +38,7 @@ def quant_score_pallas(
     scales: jax.Array,    # [N, 1] fp32 (column layout — scalar blocks)
     ids: jax.Array,       # [B, W] int32 in [0, N)
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """scores [B, W] fp32 with scores[b, w] =
     (queries[b] . codes[ids[b, w]]) * scales[ids[b, w]]."""
@@ -55,5 +59,5 @@ def quant_score_pallas(
         _quant_score_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, w), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids, queries, codes, scales)

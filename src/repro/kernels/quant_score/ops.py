@@ -2,8 +2,8 @@
 reshapes the scales to the kernel's column layout, and applies the contract
 mask (-1 ids -> -inf) so the output matches the ref.py oracle exactly.
 
-``interpret=None`` auto-falls back to interpret mode off-TPU, like the other
-fused kernels.
+``interpret=None`` interprets on the CPU backend only, like the other fused
+kernels (kernels/common.resolve_interpret).
 """
 from __future__ import annotations
 
@@ -27,8 +27,6 @@ def quant_score(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Drop-in for quant_score_ref backed by the fused Pallas kernel."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     safe = jnp.maximum(ids.astype(jnp.int32), 0)
     out = quant_score_pallas(
         queries.astype(jnp.float32),

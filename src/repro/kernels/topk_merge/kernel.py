@@ -12,10 +12,13 @@ grid = (B/bb,): one query tile per step; everything fits VMEM
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import resolve_interpret
 
 NEG_INF = float("-inf")
 
@@ -62,7 +65,7 @@ def _merge_kernel(
 
 
 def topk_merge_pallas(
-    pool_s, pool_i, pool_c, new_s, new_i, new_c, *, bb: int = 128, interpret: bool = True
+    pool_s, pool_i, pool_c, new_s, new_i, new_c, *, bb: int = 128, interpret: Optional[bool] = None
 ):
     """pool_*: [B, L] (fp32 / int32 / int32 0-1 flag); new_*: [B, M].
     Returns merged top-L (scores, ids, checked) by descending score."""
@@ -84,5 +87,5 @@ def topk_merge_pallas(
             jax.ShapeDtypeStruct((b, l), jnp.int32),
             jax.ShapeDtypeStruct((b, l), jnp.int32),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pool_s, pool_i, pool_c, new_s, new_i, new_c)

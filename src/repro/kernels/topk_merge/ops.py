@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,7 @@ from repro.kernels.topk_merge.kernel import topk_merge_pallas, NEG_INF
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def topk_merge(pool_s, pool_i, pool_c, new_s, new_i, new_c, *, interpret: bool = True):
+def topk_merge(pool_s, pool_i, pool_c, new_s, new_i, new_c, *, interpret: Optional[bool] = None):
     b = pool_s.shape[0]
     bb = min(128, b)
     bp = -(-b // bb) * bb

@@ -1,15 +1,19 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/collective analyses.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch internlm2-20b \
       --shape train_4k --mesh both --out experiments/dryrun
 
-The XLA_FLAGS line above MUST run before any jax import (device count locks
-at first init); nothing else in the repo sets it globally.
+Run as a script, it forces 512 host devices before jax is imported (the
+device count locks at first init).  Importing the module sets nothing.
 """
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
+
 import argparse
 import json
 import re

@@ -7,9 +7,14 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh_compat  # noqa: F401  (re-export; the shim
-# lives in repro.compat with the other jax-version fallbacks)
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes (jax defaults to Explicit axes,
+    whose sharding-in-types rules the shard_map drivers do not use)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,13 +24,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     traffic is one gradient reduction per step."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int | None = None, axis: str = "model"):
     """Small CPU mesh for tests/examples (uses however many devices exist)."""
     n = n_devices or len(jax.devices())
-    return make_mesh_compat((n,), (axis,))
+    return make_mesh((n,), (axis,))
 
 
 def batch_axes_of(mesh) -> tuple:

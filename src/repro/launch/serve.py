@@ -8,8 +8,8 @@ stage (--index ipnsw_plus), the ip-NSW baseline, or the exact scan.
       [--partition norm_bands] [--route upper_bound]
 
 With --shards > 1, items are row-sharded into shard-local sub-indexes and
-queries fan out via shard_map (requires that many local devices; use
-XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU).
+queries fan out via shard_map over a ("model",) mesh of N devices, one shard
+per device: the process must see at least N accelerator devices.
 ``--partition norm_bands`` cuts the catalog into descending-norm bands and
 ``--route upper_bound`` lets each query skip shards whose Cauchy-Schwarz
 bound cannot reach its running k-th score (core/distributed.py); the report
@@ -41,9 +41,10 @@ import jax.numpy as jnp
 from repro.core import IpNSW, IpNSWPlus, exact_topk, recall_at_k
 from repro.data import mips_dataset, mips_queries
 from repro.launch import serve_loop as sl
+from repro.launch.compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--index", default="ipnsw_plus",
                     choices=["bruteforce", "ipnsw", "ipnsw_plus"])
@@ -114,7 +115,7 @@ def main():
                     help="thread an obs.TraceContext through every walk: "
                          "per-norm-band eval histograms + hub hits ride "
                          "along at unchanged walk outputs (repro.obs)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.shards <= 1 and (args.route != "none"
                              or args.partition != "roundrobin"
@@ -125,6 +126,7 @@ def main():
         raise SystemExit("--storage tiered rides the routed two-phase walk; "
                          "add --route upper_bound")
 
+    enable_compile_cache()
     compile_events0 = sl.xla_compile_events()
 
     items = jnp.asarray(mips_dataset(args.n_items, args.dim, args.profile, seed=0))
@@ -146,13 +148,20 @@ def main():
     trace_ctx = None
     route_note = ""
     if args.shards > 1:
-        from repro.core.distributed import build_sharded, sharded_search
-
-        assert len(jax.devices()) >= args.shards, (
-            f"need {args.shards} devices; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={args.shards}"
+        from repro.core.distributed import (
+            SHARD_AXIS, build_sharded, sharded_search,
         )
-        index = build_sharded(items, args.shards,
+
+        if len(jax.devices()) < args.shards:
+            raise SystemExit(
+                f"--shards {args.shards} places one shard per device, but "
+                f"only {len(jax.devices())} device(s) are visible")
+        from repro.launch.mesh import make_mesh
+
+        # One shard per device: the build places shard s on device s.
+        mesh = make_mesh((args.shards,), (SHARD_AXIS,),
+                         devices=jax.devices()[:args.shards])
+        index = build_sharded(items, args.shards, mesh=mesh,
                               plus=args.index == "ipnsw_plus",
                               build_backend=args.build_backend,
                               backend=args.backend,
@@ -162,19 +171,15 @@ def main():
                               partition=args.partition,
                               max_degree=16, ef_construction=32,
                               insert_batch=512)
-        from repro.launch.mesh import make_mesh_compat
-
-        mesh = make_mesh_compat((args.shards,), ("model",))
-        # jit the whole fan-out: sharded_search alone rebuilds its shard_map
-        # closure per call, so without this the "warmup" would not cache
-        # anything and the timed call would still pay trace+compile.
-        # Routing happens INSIDE the program (two-phase masked walk) so the
-        # jit stays compile-once; return_stats threads the visit counts out.
-        search = jax.jit(functools.partial(
+        # sharded_search is jitted per static configuration, so the warmup
+        # call compiles the program the timed call reuses.  Routing happens
+        # INSIDE the program (two-phase masked walk) so it stays
+        # compile-once; return_stats threads the visit counts out.
+        search = functools.partial(
             sharded_search, mesh=mesh, k=args.k, ef=args.ef,
             backend=args.backend, storage=args.storage,
             route=args.route, return_stats=True,
-            plus=args.index == "ipnsw_plus"))
+            plus=args.index == "ipnsw_plus")
         jax.block_until_ready(search(index, queries)[0])  # compile warmup
         t0 = time.perf_counter()
         ids, _, evals, rstats = search(index, queries)

@@ -82,27 +82,41 @@ from repro.core.search import beam_search
 # XLA compile-event cross-check (jax.monitoring hook)
 # --------------------------------------------------------------------------
 
-_COMPILE_EVENTS = {"n": 0}
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_COMPILE_EVENTS = {"n": 0, "secs": 0.0, "cache_hits": 0}
 
 
-def _count_compile_event(event: str, *args, **kwargs) -> None:
-    if "compile" in event:
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
         _COMPILE_EVENTS["n"] += 1
+        _COMPILE_EVENTS["secs"] += duration_secs
 
 
-try:  # pragma: no cover - listener registration is environment-dependent
-    from jax import monitoring as _jax_monitoring
+def _on_event(event: str, **kwargs) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _COMPILE_EVENTS["cache_hits"] += 1
 
-    _jax_monitoring.register_event_listener(_count_compile_event)
-    _jax_monitoring.register_event_duration_secs_listener(_count_compile_event)
-except Exception:  # monitoring API absent/changed: executor counts remain
-    pass
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 
 def xla_compile_events() -> int:
-    """Raw XLA compile events observed process-wide since import (a
-    cross-check for the executor's per-bucket cache-miss count)."""
+    """XLA backend compiles observed process-wide since import (a
+    cross-check for the executor's per-bucket cache-miss count); a program
+    loaded from the persistent compilation cache counts too."""
     return _COMPILE_EVENTS["n"]
+
+
+def xla_compile_seconds() -> float:
+    """Wall seconds spent in those backend compiles (cache loads included)."""
+    return _COMPILE_EVENTS["secs"]
+
+
+def compile_cache_hits() -> int:
+    """Programs loaded from JAX's persistent compilation cache."""
+    return _COMPILE_EVENTS["cache_hits"]
 
 
 # --------------------------------------------------------------------------
@@ -133,6 +147,10 @@ class WallClock:
     virtual = False
 
     def __init__(self):
+        self.restart()
+
+    def restart(self) -> None:
+        """Re-zero: ``ServeLoop.run`` calls this once the ladder is warm."""
         self._t0 = time.perf_counter()
 
     def now(self) -> float:
@@ -417,6 +435,15 @@ class BucketExecutor:
             query_argnum = 4
         jit_kwargs = {"donate_argnums": (query_argnum,)} if self.donate else {}
         return jax.jit(fn, **jit_kwargs)
+
+    def lower(self, bucket: Bucket):
+        """The bucket's program lowered at its serving shapes — to inspect
+        what it runs (e.g. ``"tpu_custom_call" in .as_text()`` proves the
+        walk kernel is compiled by Mosaic, not interpreted)."""
+        fn = self._programs.get(bucket) or self._build_program(bucket)
+        return fn.lower(*self._consts(),
+                        jnp.zeros((bucket.batch, self.dim()), jnp.float32),
+                        jnp.zeros((bucket.batch,), bool))
 
     def warmup(self) -> None:
         """Compile every ladder bucket on an all-pad batch (the while_loop
@@ -706,6 +733,10 @@ class ServeLoop:
                 )
         if not self.executor.warmed:
             self.executor.warmup()
+        if not self.clock.virtual:
+            # Trace times count from when the server takes traffic: the
+            # warmup compiles are set-up, never request latency.
+            self.clock.restart()
 
         events = list(getattr(churn, "events", churn or ()))
         if events and self.executor.mutable is None:

@@ -48,8 +48,8 @@ items = jnp.asarray(rng.normal(size=(2048, 16)).astype(np.float32))
 queries = jnp.asarray(rng.normal(size=(8, 16)).astype(np.float32))
 idx = build_sharded(items, 8, plus=True, max_degree=8, ef_construction=16, insert_batch=256)
 ids_ref, sc_ref, ev_ref = sharded_search_reference(idx, queries, k=5, ef=16, plus=True)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("model",))
 ids_sm, sc_sm, ev_sm = sharded_search(idx, queries, mesh=mesh, k=5, ef=16, plus=True)
 assert np.array_equal(np.asarray(ids_ref), np.asarray(ids_sm))
 assert np.allclose(np.asarray(sc_ref), np.asarray(sc_sm))
@@ -85,8 +85,8 @@ idx = build_sharded(items, 8, build_backend="scan", **kw)
 idx_host = build_sharded(items, 8, build_backend="host", **kw)
 assert np.array_equal(np.asarray(idx.ip.adj), np.asarray(idx_host.ip.adj))
 assert np.array_equal(np.asarray(idx.ang.adj), np.asarray(idx_host.ang.adj))
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("model",))
 # ang_ef/k_angular now reach the local walks (built with defaults 10/10;
 # searched with the build-time values passed explicitly)
 common = dict(k=5, ef=16, plus=True, ang_ef=10, k_angular=10)
@@ -116,8 +116,8 @@ def test_moe_sharded_matches_local(rng):
         """
 import numpy as np, jax, jax.numpy as jnp
 from repro.models import moe as M
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 d, f, E = 16, 32, 8
 params, _ = M.moe_init(jax.random.PRNGKey(__SEED__ % 2**31), d, f, E, jnp.float32)
 x = jnp.asarray(np.random.default_rng(__SEED__).normal(size=(4, 8, d)).astype(np.float32))
@@ -142,8 +142,8 @@ def test_gnn_sharded_matches_local(rng):
         """
 import numpy as np, jax, jax.numpy as jnp
 from repro.models import gnn as G
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = G.GNNConfig(n_layers=2, d_hidden=16, d_feat=8, d_edge=4, remat=False)
 params, _ = G.init(jax.random.PRNGKey(__SEED__ % 2**31), cfg)
 rng = np.random.default_rng(__SEED__)
@@ -176,8 +176,8 @@ def test_compressed_allreduce_error_feedback(rng):
         """
 import numpy as np, jax, jax.numpy as jnp
 from repro.train.compress import make_compressed_allreduce
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 f = make_compressed_allreduce(mesh, ("data",))
 rng = np.random.default_rng(__SEED__)
 x = jnp.asarray(rng.normal(size=(8, 512)).astype(np.float32))
@@ -210,8 +210,8 @@ import dataclasses
 from repro.models import transformer as tf, layers as L
 from repro.train import adamw_init, adamw_update
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 L.set_batch_axes_for_mesh(mesh)
 cfg = tf.TransformerConfig(name="t", n_layers=2, d_model=32, n_heads=4, n_kv=2,
     head_dim=8, d_ff=64, vocab=64, dtype=jnp.float32, attn_chunk=8, remat=False,

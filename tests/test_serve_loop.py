@@ -157,7 +157,7 @@ def test_padding_equivalence_valid_mask_direct():
 
 
 def test_padding_equivalence_pallas_backend():
-    """Same pin through the fused-kernel walk (interpret mode off-TPU)."""
+    """Same pin through the fused-kernel walk (interpret mode on CPU)."""
     idx = _index()
     live = mips_queries(2, D, seed=41)
     q = np.zeros((4, D), np.float32)

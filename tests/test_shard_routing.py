@@ -83,11 +83,11 @@ def test_shard_routing_matrix(rng):
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.distributed import build_sharded, sharded_search, sharded_search_reference
 from repro.data.synthetic import mips_dataset, mips_queries
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
 SEED = {seed}
 N, D, P, K, EF = 510, 16, 4, 10, 32   # ragged tail: Nloc=128, count[3]=126
-mesh = make_mesh_compat((P,), ("model",))
+mesh = make_mesh((P,), ("model",))
 kw = dict(partition="norm_bands", storage="int8",   # stores cover f32 too
           build_backend="scan", max_degree=8, ef_construction=16,
           insert_batch=64)
@@ -111,7 +111,7 @@ for profile in {profiles}:
                 ids_d, sc_d, ev_d = sharded_search(idx, queries, mesh=mesh, **common)
                 assert np.array_equal(np.asarray(ids_o), np.asarray(ids_d)), tag
                 # ids bit-identical; scores to fp tolerance (shard_map and
-                # vmap contract the same dots in different orders, same as
+                # lax.map may contract the same dots in different orders, as
                 # the seed pin in test_distributed.py)
                 assert np.allclose(np.asarray(sc_o), np.asarray(sc_d)), tag
                 base = recall(ids_o, gt)
