@@ -1,0 +1,2 @@
+"""The on-chip benchmark: one cell of ``BENCHMARK.json`` per run
+(``bench/run.py``).  See ``bench/README.md``."""
