@@ -65,10 +65,12 @@ def report(path: str, *, n_bins: int = 12, out=sys.stdout) -> dict:
             summary[name] = mean
             w(f"{name}: count={m['count']} mean={mean:g}\n")
 
-    ev_total = metrics.get("walk_evals_total")
+    # Hub evals and the band vector count the same walk (ip-NSW+'s G_s
+    # refine walk); walk_evals_total counts every walk of every dispatch.
     hub = metrics.get("walk_hub_evals_total")
-    if ev_total and hub and ev_total["value"] > 0:
-        frac = hub["value"] / ev_total["value"]
+    band_total = sum(band["values"]) if band is not None else 0
+    if hub and band_total > 0:
+        frac = hub["value"] / band_total
         summary["hub_eval_share"] = frac
         w(f"hub_eval_share = {frac:.4f}\n")
     return summary

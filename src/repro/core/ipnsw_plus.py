@@ -55,6 +55,8 @@ class PlusResult(NamedTuple):
     ip_evals: jax.Array     # [B]
     visited_ang: jax.Array  # [B, Va] ids scored on A_s (Fig-5 data)
     visited_ip: jax.Array   # [B, Vi] ids scored on G_s
+    ang_steps: jax.Array    # [] while_loop iterations of the A_s walk
+    ip_steps: jax.Array     # [] while_loop iterations of the G_s walk
     trace: "Optional[object]" = None  # obs.WalkTrace of the G_s refine walk
     #   (the stage the paper's norm-bias figures measure); None untraced
 
@@ -150,6 +152,8 @@ def _search_plus(
         ip_evals=ip.evals,
         visited_ang=ang.visited,
         visited_ip=ip.visited,
+        ang_steps=ang.steps,
+        ip_steps=ip.steps,
         trace=ip.trace,
     )
 

@@ -230,4 +230,5 @@ def commit_merge_pallas(
             pltpu.SemaphoreType.DMA((3,)),
         ],
         interpret=resolve_interpret(interpret),
+        name="commit_merge",
     )(utgt, bucket_ids, bucket_scores, adj, rows)

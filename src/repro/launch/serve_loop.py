@@ -57,13 +57,19 @@ and ``trace_ctx=`` threads an ``obs.TraceContext`` through every dispatch
 so per-norm-band walk histograms ride along (docs/ARCHITECTURE.md,
 "The observability layer").  Every registry record carries LOOP-clock
 values — the no-wall-time property above is preserved, and a VirtualClock
-run exports a deterministic registry.
+run exports a deterministic registry.  Host time goes into profiler spans
+(``obs.span``) that tile each loop iteration: ``loop.admit``,
+``loop.sleep.arrival``, ``loop.sleep.coalesce``, ``loop.form``,
+``executor.put``, ``executor.launch``, ``executor.fetch``, ``loop.demux``,
+and ``host.gc`` around collections while a wall-clock run with a registry
+serves.
 
 See docs/ARCHITECTURE.md ("The serving layer") and benchmarks/serve_bench.py
 for the p50/p99/QPS/occupancy rows built on top of this loop.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time  # WallClock only — the loop itself never reads wall time
 from collections import deque
@@ -74,6 +80,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.ipnsw import IpNSW
 from repro.core.ipnsw_plus import IpNSWPlus
 from repro.core.search import beam_search
@@ -307,7 +314,7 @@ def _ipnsw_bucket(graph, store, live, trace, queries, valid, *, k, ef,
         backend=backend, storage=storage, store=store, valid=valid, live=live,
         trace=trace,
     )
-    return r.ids, r.scores, r.evals, r.trace
+    return r.ids, r.scores, r.evals, r.steps, r.trace
 
 
 def _plus_bucket(ang_graph, ip_graph, ang_store, ip_store, live, trace,
@@ -321,7 +328,7 @@ def _plus_bucket(ang_graph, ip_graph, ang_store, ip_store, live, trace,
         max_steps=2 * ef, ang_max_steps=2 * max(ang_ef, k_angular),
         backend=backend, storage=storage,
     )
-    return r.ids, r.scores, r.evals, r.trace
+    return r.ids, r.scores, r.evals, r.ang_steps + r.ip_steps, r.trace
 
 
 class BucketExecutor:
@@ -459,18 +466,41 @@ class BucketExecutor:
     def run(self, bucket: Bucket, queries: np.ndarray, valid: np.ndarray):
         """Dispatch one padded bucket; returns (ids, scores, evals) as
         host arrays.  ``queries`` [bucket.batch, d] fp32 is consumed (it may
-        be donated) — callers build a fresh buffer per dispatch."""
-        fn = self._programs.get(bucket)
-        if fn is None:
-            fn = self._build_program(bucket)
-            self._programs[bucket] = fn
-            self.compile_log.append(
-                (bucket, "steady" if self._steady else "warmup")
-            )
-        ids, scores, evals, walk = fn(*self._consts(), jnp.asarray(queries),
-                                      jnp.asarray(valid))
-        self._record_walk(walk, np.asarray(valid))
-        return np.asarray(ids), np.asarray(scores), np.asarray(evals)
+        be donated) — callers build a fresh buffer per dispatch.
+
+        Spans: ``executor.put`` (operands and the two transfers),
+        ``executor.launch`` (the jitted call until it returns) and
+        ``executor.fetch`` (the results back, which waits for the device).
+        The program's fourth output, its ``beam_step`` launches, is fetched
+        only with a registry attached, its copy started with the launch so
+        it does not add a transfer of its own after the results."""
+        with obs.span("executor.put"):
+            fn = self._programs.get(bucket)
+            if fn is None:
+                fn = self._build_program(bucket)
+                self._programs[bucket] = fn
+                self.compile_log.append(
+                    (bucket, "steady" if self._steady else "warmup")
+                )
+            args = (*self._consts(), jnp.asarray(queries), jnp.asarray(valid))
+        with obs.span("executor.launch"):
+            ids, scores, evals, steps, walk = fn(*args)
+            if self.registry is not None:
+                steps.copy_to_host_async()
+        with obs.span("executor.fetch"):
+            valid = np.asarray(valid)
+            out = np.asarray(ids), np.asarray(scores), np.asarray(evals)
+            self._record_walk(walk, valid)
+            if self.registry is not None:
+                self.registry.counter(
+                    "walk_steps_total",
+                    "beam_step launches: while_loop iterations of every walk",
+                ).inc(int(steps))
+                self.registry.counter(
+                    "walk_evals_total",
+                    "similarity evaluations of every walk, live rows",
+                ).inc(int(out[2][valid].sum()))
+        return out
 
     def _record_walk(self, walk, valid: np.ndarray) -> None:
         """Stash this dispatch's walk telemetry (``last_walk``: batch-summed
@@ -502,9 +532,6 @@ class BucketExecutor:
                 "walk_hub_evals_total",
                 "evaluations landing on the top-in-degree hub set (Fig-4)",
             ).inc(hub)
-            reg.counter(
-                "walk_evals_total", "total similarity evaluations",
-            ).inc(float(band.sum()))
 
 
 # --------------------------------------------------------------------------
@@ -694,18 +721,20 @@ class ServeLoop:
         lat_h = reg.histogram(
             "serve_latency_seconds", "arrival -> finish (loop clock)",
         )
-        miss = reg.counter("serve_deadline_miss_total", "late responses")
+        arrival = np.fromiter((r.arrival_t for r in batch), np.float64, n)
+        wait_h.observe_many(now - arrival)
+        lat_h.observe_many(finish - arrival)
+        late = 0
         for r in batch:
-            wait_h.observe(now - r.arrival_t)
-            lat_h.observe(finish - r.arrival_t)
-            if finish > r.deadline_t:
-                miss.inc()
+            met = finish <= r.deadline_t
+            late += not met
             reg.event(
                 "response", finish, rid=r.rid,
                 latency_s=finish - r.arrival_t,
                 queue_wait_s=now - r.arrival_t,
-                deadline_met=finish <= r.deadline_t,
+                deadline_met=met,
             )
+        reg.counter("serve_deadline_miss_total", "late responses").inc(late)
         ev = {"batch": bucket.batch, "ef": bucket.ef, "n": n,
               "degraded": degraded}
         walk = self.executor.last_walk
@@ -715,6 +744,36 @@ class ServeLoop:
             ev["steps_mean"] = walk["steps_mean"]
         reg.event("dispatch", now, **ev)
 
+    def _next_wake(self, queue: List[Request], pending: deque,
+                   churn_q: deque, now: float):
+        """(time, span name) to sleep until, or None to dispatch now.
+        Sorts ``queue`` into admission order when it is not empty."""
+        if not queue:
+            # Wake for whichever comes first: the next arrival or the next
+            # churn event.
+            t = pending[0].arrival_t
+            if churn_q:
+                t = min(t, churn_q[0].t)
+            return t, "loop.sleep.arrival"
+        queue.sort(key=self._order)
+        head = queue[0]
+        max_b = self.ladder.max_batch
+        next_arrival = pending[0].arrival_t if pending else None
+        dispatch_by = head.deadline_t - self.service_model.service_s(
+            Bucket(max_b, self.ladder.ef_pref(head.ef))
+        )
+        if (len(queue) < max_b and next_arrival is not None
+                and next_arrival <= dispatch_by and now < dispatch_by):
+            # Coalesce: waiting for the next arrival cannot cost the head
+            # its preferred service — sleep to the earliest of the arrival,
+            # the head's dispatch-by point and the next churn event (which
+            # must apply before the dispatch it precedes).
+            t = min(next_arrival, dispatch_by)
+            if churn_q:
+                t = min(t, churn_q[0].t)
+            return max(t, now), "loop.sleep.coalesce"
+        return None
+
     def run(self, requests: Iterable[Request], churn=None) -> ServeStats:
         """``churn`` (optional) is a ``core.mutation.ChurnTrace`` — or any
         sequence of ``ChurnEvent`` — replayed against the loop's
@@ -722,15 +781,24 @@ class ServeLoop:
         loop's clock passes their timestamps, never mid-batch, and events
         dated past the last response are drained at the end (the trace's
         turnover always completes).  Requires the executor to wrap a
-        MutableIndex."""
-        trace = sorted(requests, key=lambda r: (r.arrival_t, r.rid))
-        d = self.executor.dim()
-        for r in trace:
-            if np.asarray(r.query).shape != (d,):
-                raise ValueError(
-                    f"request {r.rid}: query shape {np.asarray(r.query).shape}"
-                    f" != ({d},)"
-                )
+        MutableIndex.
+
+        A wall-clock run with a registry wraps each garbage collection in a
+        ``host.gc`` span until it returns."""
+        with (obs.gc_spans() if self.registry is not None
+              and not self.clock.virtual else contextlib.nullcontext()):
+            return self._run(requests, churn)
+
+    def _run(self, requests: Iterable[Request], churn) -> ServeStats:
+        with obs.span("loop.admit"):
+            trace = sorted(requests, key=lambda r: (r.arrival_t, r.rid))
+            d = self.executor.dim()
+            for r in trace:
+                if np.asarray(r.query).shape != (d,):
+                    raise ValueError(
+                        f"request {r.rid}: query shape "
+                        f"{np.asarray(r.query).shape} != ({d},)"
+                    )
         if not self.executor.warmed:
             self.executor.warmup()
         if not self.clock.virtual:
@@ -754,71 +822,54 @@ class ServeLoop:
         max_b = self.ladder.max_batch
 
         while pending or queue:
-            now = self.clock.now()
-            self._apply_churn(churn_q, now, applied)
-            while pending and pending[0].arrival_t <= now:
-                queue.append(pending.popleft())
-            if not queue:
-                # Wake for whichever comes first: the next arrival or the
-                # next churn event.
-                t = pending[0].arrival_t
-                if churn_q:
-                    t = min(t, churn_q[0].t)
-                self.clock.sleep_until(t)
+            # loop.form covers the whole scheduling decision, so the spans
+            # tile an iteration: form, then a sleep or a dispatch.
+            with obs.span("loop.form"):
+                now = self.clock.now()
+                self._apply_churn(churn_q, now, applied)
+                while pending and pending[0].arrival_t <= now:
+                    queue.append(pending.popleft())
+                wake = self._next_wake(queue, pending, churn_q, now)
+                if wake is None:
+                    batch = queue[:max_b]
+                    del queue[:len(batch)]
+                    bucket_batch = self.ladder.batch_for(len(batch))
+                    ef, degraded = self._choose_ef(batch, bucket_batch, now)
+                    bucket = Bucket(bucket_batch, ef)
+                    padded = np.zeros((bucket.batch, d), np.float32)
+                    for i, r in enumerate(batch):
+                        padded[i] = r.query
+                    valid = np.arange(bucket.batch) < len(batch)
+            if wake is not None:
+                with obs.span(wake[1]):
+                    self.clock.sleep_until(wake[0])
                 continue
 
-            queue.sort(key=self._order)
-            head = queue[0]
-            next_arrival = pending[0].arrival_t if pending else None
-            dispatch_by = head.deadline_t - self.service_model.service_s(
-                Bucket(max_b, self.ladder.ef_pref(head.ef))
-            )
-            if (len(queue) < max_b and next_arrival is not None
-                    and next_arrival <= dispatch_by and now < dispatch_by):
-                # Coalesce: waiting for the next arrival cannot cost the
-                # head its preferred service — sleep to the earliest of the
-                # arrival, the head's dispatch-by point and the next churn
-                # event (which must apply before the dispatch it precedes).
-                t = min(next_arrival, dispatch_by)
-                if churn_q:
-                    t = min(t, churn_q[0].t)
-                self.clock.sleep_until(max(t, now))
-                continue
-
-            batch = queue[:max_b]
-            del queue[:len(batch)]
-            bucket_batch = self.ladder.batch_for(len(batch))
-            ef, degraded = self._choose_ef(batch, bucket_batch, now)
-            bucket = Bucket(bucket_batch, ef)
-
-            padded = np.zeros((bucket.batch, d), np.float32)
-            for i, r in enumerate(batch):
-                padded[i] = r.query
-            valid = np.arange(bucket.batch) < len(batch)
             ids, scores, _ = self.executor.run(bucket, padded, valid)
 
-            if self.clock.virtual:
-                finish = now + self.service_model.service_s(bucket)
-                self.clock.sleep_until(finish)
-            else:
-                finish = self.clock.now()
-
-            for i, r in enumerate(batch):
-                responses.append(Response(
-                    rid=r.rid, ids=ids[i], scores=scores[i],
-                    ef_request=r.ef, ef_served=ef, bucket=bucket,
-                    arrival_t=r.arrival_t, dispatch_t=now, finish_t=finish,
-                    deadline_t=r.deadline_t,
-                    deadline_met=finish <= r.deadline_t,
-                    degraded=degraded,
+            with obs.span("loop.demux"):
+                if self.clock.virtual:
+                    finish = now + self.service_model.service_s(bucket)
+                    self.clock.sleep_until(finish)
+                else:
+                    finish = self.clock.now()
+                for i, r in enumerate(batch):
+                    responses.append(Response(
+                        rid=r.rid, ids=ids[i], scores=scores[i],
+                        ef_request=r.ef, ef_served=ef, bucket=bucket,
+                        arrival_t=r.arrival_t, dispatch_t=now,
+                        finish_t=finish, deadline_t=r.deadline_t,
+                        deadline_met=finish <= r.deadline_t,
+                        degraded=degraded,
+                    ))
+                batches.append(BatchRecord(
+                    seq=len(batches), dispatch_t=now, finish_t=finish,
+                    bucket=bucket, rids=tuple(r.rid for r in batch),
+                    ef_served=ef,
                 ))
-            batches.append(BatchRecord(
-                seq=len(batches), dispatch_t=now, finish_t=finish,
-                bucket=bucket, rids=tuple(r.rid for r in batch),
-                ef_served=ef,
-            ))
-            if self.registry is not None:
-                self._record_dispatch(bucket, batch, now, finish, degraded)
+                if self.registry is not None:
+                    self._record_dispatch(bucket, batch, now, finish,
+                                          degraded)
 
         # Drain churn events dated past the last response so the trace's
         # turnover completes even when traffic stops first.

@@ -19,8 +19,10 @@ from repro.obs.registry import (
     Histogram,
     MetricsRegistry,
     VectorCounter,
+    gc_spans,
     get_registry,
     set_registry,
+    span,
 )
 from repro.obs.trace import (
     TraceContext,
@@ -38,6 +40,7 @@ __all__ = [
     "TraceContext",
     "VectorCounter",
     "WalkTrace",
+    "gc_spans",
     "get_registry",
     "load_jsonl",
     "make_trace_context",
@@ -46,6 +49,7 @@ __all__ = [
     "render_band_table",
     "render_latency_timeline",
     "set_registry",
+    "span",
     "step_of_column",
     "top_band_share",
     "walk_trace",

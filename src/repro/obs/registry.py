@@ -15,9 +15,15 @@ per subsystem.  Design constraints, in order:
       ``bench=obs_overhead`` row (benchmarks/serve_bench.py) measures the
       always-on cost against an uninstrumented run and
       scripts/check_bench_json.py FAILS CI when it exceeds 5%.
-  dependency-free — pure Python/numpy; nothing in ``repro.obs`` imports
-      ``repro.core``, so every layer (core, kernels, launch) may import the
-      registry without cycles.
+  dependency-free — Python, numpy and jax's profiler annotation; nothing
+      in ``repro.obs`` imports ``repro.core``, so every layer (core,
+      kernels, launch) may import the registry without cycles.
+
+Time goes in profiler spans, counts go in the registry: ``span(name)`` is a
+``jax.profiler.TraceAnnotation``, a host span on the same clock as the
+device ops of a profiler trace, and costs one TraceMe check (about 1 us)
+when no profiler runs.  It reads no Python clock, so the serving loop opens
+spans on a VirtualClock and its registry stays deterministic.
 
 Metric types:
   Counter       — monotonically increasing float (``_total`` names).
@@ -37,11 +43,13 @@ traffic).
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 # Default latency-style buckets (seconds): ~exponential, 100us .. 10s.
@@ -49,6 +57,40 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
     1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2, 5e-2,
     1e-1, 2.5e-1, 5e-1, 1.0, 2.5, 5.0, 10.0,
 )
+
+
+def span(name: str):
+    """A host span on the profiler's timeline (``with span(name): ...``).
+    No fields: trace reductions match event names exactly."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+GC_SPAN = "host.gc"   # trace reductions match this name
+
+
+@contextmanager
+def gc_spans():
+    """Wrap each Python garbage collection inside the block in a
+    ``GC_SPAN`` span.  A profiler annotation only, never a registry record:
+    collections are not deterministic.  The hook is removed when the block
+    exits, raised or not."""
+    open_spans = []
+
+    def hook(phase, info):
+        if phase == "start":
+            s = span(GC_SPAN)
+            s.__enter__()
+            open_spans.append(s)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(hook)
+        while open_spans:
+            open_spans.pop().__exit__(None, None, None)
 
 
 class Counter:
@@ -112,6 +154,19 @@ class Histogram:
                 self.counts[i] += 1
                 return
         self.counts[-1] += 1
+
+    def observe_many(self, values) -> None:
+        """``observe`` each of ``values`` in order, in one pass: a value
+        lands in the first bucket whose upper bound it does not exceed, and
+        the sum accumulates in the same order as repeated ``observe``."""
+        v = np.asarray(values, np.float64).ravel()
+        if not v.size:
+            return
+        slot = np.searchsorted(self.uppers, v, side="left")
+        add = np.bincount(slot, minlength=len(self.counts))
+        self.counts = [c + int(a) for c, a in zip(self.counts, add)]
+        self.count += int(v.size)
+        self.sum = float(np.cumsum(np.concatenate([[self.sum], v]))[-1])
 
     def mean(self) -> float:
         return self.sum / self.count if self.count else 0.0
@@ -194,15 +249,17 @@ class MetricsRegistry:
 
     @contextmanager
     def span(self, name: str, help: str = ""):
-        """Time a host-side phase into ``{name}_seconds``.  Measures the
-        driver's wall time; jax dispatch is async, so device work may
-        overlap the span unless the caller blocks — documented per site."""
+        """Time a host-side phase into ``{name}_seconds``, inside a profiler
+        ``span(name)``.  Measures the caller's host wall time; jax dispatch
+        is async, so device work may overlap the span unless the caller
+        blocks — documented per site."""
         h = self.histogram(f"{name}_seconds", help)
-        t0 = time.perf_counter()
-        try:
-            yield h
-        finally:
-            h.observe(time.perf_counter() - t0)
+        with span(name):
+            t0 = time.perf_counter()
+            try:
+                yield h
+            finally:
+                h.observe(time.perf_counter() - t0)
 
     # -- timeline ----------------------------------------------------------
 

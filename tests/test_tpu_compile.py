@@ -12,6 +12,7 @@ pytest-xdist worker collects the same tests and only the worker that runs
 this file loads the TPU library; the compiles stay in this process.
 """
 import os
+import re
 
 import pytest
 import jax
@@ -46,6 +47,14 @@ def _compile(fn, *shapes):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
+
+
+def _kernel_ops(compiled):
+    """Names of the compiled program's Mosaic custom calls: the op names a
+    device trace carries for the kernels."""
+    return re.findall(
+        r'(%[\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())
 
 
 def _walk_shapes(one_chip, quantized, has_live, ef=EF, m=M):
@@ -155,3 +164,53 @@ def test_sharded_reference_walk_compiles(one_chip, monkeypatch):
             i, q, k=10, ef=EF, plus=False, backend="pallas"),
         index, s((B, D), jnp.float32),
     )
+
+
+def test_commit_merge_kernel_carries_its_name(one_chip):
+    from repro.kernels.commit_merge.kernel import commit_merge_pallas
+    from repro.kernels.common import LANES, round_up, slots_per_node, \
+        row_width
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tile, g = 8, B * M
+    compiled = _compile(
+        lambda *a: commit_merge_pallas(*a, degree=M, interpret=False),
+        s((g // tile, tile, 1), jnp.int32),
+        s((g // tile, tile, B), jnp.int32),
+        s((g // tile, tile, B), jnp.float32),
+        s((round_up(N * slots_per_node(M), LANES) // LANES, 1, LANES),
+          jnp.int32),
+        s((N, 1, row_width(D, False)), jnp.float32),
+    )
+    ops = _kernel_ops(compiled)
+    assert ops and all(op.startswith("%commit_merge.") for op in ops)
+
+
+def test_walk_kernel_op_keeps_the_name_the_trace_reduction_reads(
+        one_chip, monkeypatch):
+    """The benchmark finds the walk kernel's device time by the op name
+    ``%beam_step_on.N`` (``bench/tracing.py``, ``WALK_KERNEL``): the
+    custom call takes the name of its jitted wrapper, ``beam_step_on``.  A
+    ``name=`` on its ``pallas_call`` would rename the op (to
+    ``%beam_step.N``) and silence that reading."""
+    import functools
+
+    from repro.core.graph import GraphIndex
+    from repro.launch import serve_loop as sl
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = 50_000
+    graph = GraphIndex(adj=s((n, M), jnp.int32), items=s((n, D), jnp.float32),
+                       size=s((), jnp.int32), entry=s((), jnp.int32),
+                       entry_norm=s((), jnp.float32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(
+        functools.partial(sl._ipnsw_bucket, k=10, ef=EF, backend="pallas",
+                          storage="f32"),
+        graph, None, None, None, s((8, D), jnp.float32), s((8,), jnp.bool_))
+    ops = _kernel_ops(compiled)
+    assert ops and all(op.startswith("%beam_step_on.") for op in ops)
