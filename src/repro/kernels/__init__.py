@@ -3,7 +3,9 @@ run in interpret mode on the CPU backend (``common.resolve_interpret``).
 
   mips_topk    — tiled exact-MIPS linear scan + streaming top-k (MXU)
   gather_score — scalar-prefetch fused row-gather + dot (beam expansion)
-  topk_merge   — in-VMEM candidate-pool merge (Algorithm 1 line 7-8)
+  topk_merge   — standalone in-VMEM candidate-pool merge (Algorithm 1 line
+                 7-8) by an L-pass selection network; the walk kernel merges
+                 by rank instead (beam_step.kernel.rank_merge)
   beam_step    — fused full Algorithm-1 iteration (select + gather + dedup +
                  score + merge in VMEM); the "pallas" walk backend (DESIGN §3)
   commit_merge — fused reverse-link top-M merge of the Algorithm-2 batched

@@ -1,11 +1,11 @@
 """Fused beam-step Pallas TPU kernel — one full Algorithm-1 iteration for a
 tile of 8 queries in VMEM, no HBM round-trip between stages.
 
-Composes the two existing building blocks into a single kernel:
-  * gather_score's data-dependent row gather (here via explicit async DMA,
-    because the gathered ids are *computed inside* the kernel from the pool
-    state, so a scalar-prefetch BlockSpec cannot express them), and
-  * topk_merge's L-pass masked-max selection network (``masked_top_l``).
+Builds on gather_score's data-dependent row gather (here via explicit
+async DMA, because the gathered ids are *computed inside* the kernel from
+the pool state, so a scalar-prefetch BlockSpec cannot express them) and
+ends with a rank merge (``rank_merge``) of the sorted pool and the M new
+neighbours.
 
 Per grid step (one tile of ``QUERY_TILE`` = 8 queries, one sublane each):
   1. select every query's best unchecked pool slot (pool sorted desc =>
@@ -15,7 +15,11 @@ Per grid step (one tile of ``QUERY_TILE`` = 8 queries, one sublane each):
   3. DMA the 8·M neighbor item rows HBM->VMEM — all started before any
      wait, so on TPU the fetches overlap;
   4. mask ids against the visited ring buffer, dot the rows with their query
-     (MXU), and merge all 8 pools at once — all without leaving VMEM.
+     (MXU), and merge all 8 pools at once — all without leaving VMEM.  The
+     merge relies on the same invariant as step 1: the pool arrives sorted
+     desc (the walk seeds it with ``lax.top_k`` and every later pool is this
+     kernel's own output), so each new neighbour's place is a count, not a
+     selection pass over L + M candidates.
 
 Only the new pool state, the masked neighbor ids and three counters per
 query go back to HBM.  The XLA reference path materializes the gathered
@@ -55,9 +59,54 @@ from repro.kernels.common import (
     slots_per_node,
     unpack_codes,
 )
-from repro.kernels.topk_merge.kernel import NEG_INF, masked_top_l
+from repro.kernels.beam_step.ref import NEG_INF
 
 _LANE_SHIFT = LANES.bit_length() - 1
+
+
+def rank_merge(pool_s, pool_i, pool_c, new_s, new_i, new_c):
+    """Top-L of ``[pool, new]`` in ``lax.top_k`` order, for a pool sorted desc.
+
+    ``pool_*`` are ``[T, L]`` (scores, ids, checked), ``new_*`` ``[T, M]``
+    in any order.  ``lax.top_k`` on the concatenation orders candidates by
+    score and breaks ties by position: pool slots before new entries, new
+    entries by index.  With the pool sorted desc, slot i lands at
+    ``i + #{new k : s_k > s_i}`` and new entry j at
+    ``#{pool i : s_i >= s_j} + #{new k : s_k > s_j, or s_k == s_j and k < j}``;
+    positions >= L fall off.  Those are M independent counts, not a chain of
+    L selection passes.  -inf scores (empty slots, masked neighbours) follow
+    the same order, so every payload lands where ``lax.top_k`` puts it.
+    Plain jnp plus lane rolls: runs inside a Pallas kernel and outside one.
+    """
+    t, l = pool_s.shape
+    m = new_s.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (t, l), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, m), 1)
+    shift = jnp.zeros((t, l), jnp.int32)   # new entries ahead of each slot
+    ahead = jnp.zeros((t, m), jnp.int32)   # new entries ahead of each new one
+    for k in range(m):
+        s_k = new_s[:, k:k + 1]
+        shift += (s_k > pool_s).astype(jnp.int32)
+        ahead += ((s_k > new_s) | ((s_k == new_s) & (col > k))).astype(
+            jnp.int32)
+
+    # Slot p holds exactly one candidate: the pool slot p - k whose shift is
+    # k, or the new entry whose position is p.
+    out_s, out_i, out_c = pool_s, pool_i, pool_c
+    for k in range(1, m + 1):
+        hit = (lane >= k) & (pltpu.roll(shift, k, 1) == k)
+        out_s = jnp.where(hit, pltpu.roll(pool_s, k, 1), out_s)
+        out_i = jnp.where(hit, pltpu.roll(pool_i, k, 1), out_i)
+        out_c = jnp.where(hit, pltpu.roll(pool_c, k, 1), out_c)
+    for j in range(m):
+        s_j = new_s[:, j:j + 1]
+        pos = ahead[:, j:j + 1] + jnp.sum(
+            (pool_s >= s_j).astype(jnp.int32), axis=1, keepdims=True)
+        hit = lane == pos
+        out_s = jnp.where(hit, s_j, out_s)
+        out_i = jnp.where(hit, new_i[:, j:j + 1], out_i)
+        out_c = jnp.where(hit, new_c[:, j:j + 1], out_c)
+    return out_s, out_i, out_c
 
 
 def _beam_step_kernel(
@@ -204,12 +253,9 @@ def _beam_step_kernel(
     nbr_scores = jnp.where(valid, scores, NEG_INF)
     nbr_ids = jnp.where(valid, nbrs, -1)
 
-    cand_s = jnp.concatenate([pool_scores, nbr_scores], axis=1)
-    cand_i = jnp.concatenate([pool_ids, nbr_ids], axis=1)
-    cand_c = jnp.concatenate(
-        [checked.astype(jnp.int32), (~valid).astype(jnp.int32)], axis=1
-    )
-    out_s, out_i, out_c = masked_top_l(cand_s, cand_i, cand_c, l)
+    out_s, out_i, out_c = rank_merge(
+        pool_scores, pool_ids, checked.astype(jnp.int32),
+        nbr_scores, nbr_ids, (~valid).astype(jnp.int32))
 
     os_ref[...] = out_s
     oi_ref[...] = out_i

@@ -33,7 +33,7 @@ def masked_top_l(cand_s, cand_i, cand_c, l: int):
     -inf scores (empty/-1 slots), and overwriting would tie them with the
     already-picked slots and re-emit a picked payload instead of advancing to
     the first unpicked slot.  Statically unrolled — lowers to VPU
-    compare/select trees; also the merge stage of the fused beam_step kernel.
+    compare/select trees.
     """
     c = cand_s.shape[1]
     col = jax.lax.broadcasted_iota(jnp.int32, cand_s.shape, 1)

@@ -435,16 +435,25 @@ def test_commit_batch_rejects_unknown_backend(rng):
 # ---------------------------------------------------------------------------
 
 
-def _random_step_state(rng, b, l, m, v, n, d):
+def _random_step_state(rng, b, l, m, v, n, d, levels=None):
     """A plausible mid-walk state: sorted pool with -1 padding, partially
-    checked slots, -1 padded adjacency and visited buffer, some done rows."""
-    items = rng.normal(size=(n, d)).astype(np.float32)
-    queries = rng.normal(size=(b, d)).astype(np.float32)
+    checked slots, -1 padded adjacency and visited buffer, some done rows.
+
+    With ``levels``, items and queries hold whole numbers in [1, levels], so
+    every inner product is an exact whole number in [d, d·levels²], and the
+    pool's scores are drawn from that range: scores tie between pool and
+    neighbours and among the neighbours."""
+    if levels is None:
+        items = rng.normal(size=(n, d)).astype(np.float32)
+        queries = rng.normal(size=(b, d)).astype(np.float32)
+        scores = rng.normal(size=(b, l))
+    else:
+        items = rng.integers(1, levels + 1, (n, d)).astype(np.float32)
+        queries = rng.integers(1, levels + 1, (b, d)).astype(np.float32)
+        scores = rng.integers(d, d * levels ** 2 + 1, (b, l))
     adj = rng.integers(-1, n, size=(n, m)).astype(np.int32)
     pool_ids = rng.integers(-1, n, size=(b, l)).astype(np.int32)
-    pool_scores = np.where(
-        pool_ids >= 0, rng.normal(size=(b, l)), -np.inf
-    ).astype(np.float32)
+    pool_scores = np.where(pool_ids >= 0, scores, -np.inf).astype(np.float32)
     order = np.argsort(-pool_scores, axis=1, kind="stable")
     pool_ids = np.take_along_axis(pool_ids, order, 1)
     pool_scores = np.take_along_axis(pool_scores, order, 1)
@@ -460,18 +469,29 @@ def _random_step_state(rng, b, l, m, v, n, d):
     )
 
 
+def _step_case(b, l, m, v, n, d, levels=None, id=None):
+    return pytest.param(b, l, m, v, n, d, levels,
+                        id=id or f"{b}-{l}-{m}-{v}-{n}-{d}")
+
+
 @pytest.mark.parametrize(
-    "b,l,m,v,n,d",
+    "b,l,m,v,n,d,levels",
     [
-        (1, 1, 1, 1, 10, 1),        # degenerate everything
-        (2, 8, 4, 12, 64, 16),      # small round shapes
-        (5, 16, 8, 40, 200, 33),    # odd d
-        (3, 7, 5, 23, 111, 129),    # odd everything, d > 128
-        (9, 64, 16, 100, 500, 48),  # paper-scale pool/degree
+        _step_case(1, 1, 1, 1, 10, 1),        # degenerate everything
+        _step_case(2, 8, 4, 12, 64, 16),      # small round shapes
+        _step_case(5, 16, 8, 40, 200, 33),    # odd d
+        _step_case(3, 7, 5, 23, 111, 129),    # odd everything, d > 128
+        _step_case(9, 64, 16, 100, 500, 48),  # paper-scale pool/degree
+        # the served walk: ef = 128, M = 16, V = 10·M + 1 + 2·ef·M
+        _step_case(16, 128, 16, 4257, 1000, 300, id="served"),
+        # whole-number scores: ties between pool and neighbours and among
+        # the neighbours, with the pool narrower than the degree, and wider
+        _step_case(16, 7, 16, 40, 60, 3, levels=2, id="ties-l7-m16"),
+        _step_case(16, 64, 16, 100, 80, 4, levels=2, id="ties-l64-m16"),
     ],
 )
-def test_beam_step_matches_ref_bit_exact(rng, b, l, m, v, n, d):
-    args = _random_step_state(rng, b, l, m, v, n, d)
+def test_beam_step_matches_ref_bit_exact(rng, b, l, m, v, n, d, levels):
+    args = _random_step_state(rng, b, l, m, v, n, d, levels)
     r = beam_step_ref(*args)
     p = beam_step(*args)
     assert np.array_equal(np.asarray(r.pool_ids), np.asarray(p.pool_ids))
@@ -482,6 +502,52 @@ def test_beam_step_matches_ref_bit_exact(rng, b, l, m, v, n, d):
     np.testing.assert_allclose(
         np.asarray(r.pool_scores), np.asarray(p.pool_scores), rtol=1e-5, atol=1e-5
     )
+
+
+def _merge_case(rng, b, l, m, all_invalid):
+    """A sorted pool and M new entries with scores from a few values, so
+    ties fall between the pool and the new entries and among the new ones;
+    -inf slots carry id -1 (empty pool slots, masked neighbours).  Some rows
+    have an empty pool, some no valid new entry; ``all_invalid`` makes every
+    row's new entries invalid, as for a tile of done queries."""
+    levels = np.array([-np.inf, 1.0, 2.0, 3.0], np.float32)
+    pool_s = -np.sort(-rng.choice(levels, (b, l)), axis=1)
+    pool_s[0] = -np.inf
+    new_s = rng.choice(levels, (b, m)).astype(np.float32)
+    new_s[1] = -np.inf
+    if all_invalid:
+        new_s[:] = -np.inf
+    pool_i = np.where(np.isinf(pool_s), -1, rng.integers(0, 50, (b, l)))
+    new_i = np.where(np.isinf(new_s), -1, rng.integers(0, 50, (b, m)))
+    pool_c = rng.integers(0, 2, (b, l))
+    new_c = np.isinf(new_s).astype(np.int32)
+    return tuple(jnp.asarray(x, dt) for x, dt in zip(
+        (pool_s, pool_i, pool_c, new_s, new_i, new_c),
+        (jnp.float32, jnp.int32, jnp.int32) * 2))
+
+
+@pytest.mark.parametrize("all_invalid", [False, True],
+                         ids=["ties", "all-invalid"])
+@pytest.mark.parametrize(
+    "l,m", [(1, 1), (7, 16), (10, 10), (64, 16), (128, 16), (33, 9)])
+def test_rank_merge_matches_top_k(rng, l, m, all_invalid):
+    """The walk kernel's merge, as plain jnp, against ``lax.top_k`` on
+    ``[pool, new]``: identical scores, ids and checked flags."""
+    import jax
+
+    from repro.kernels.beam_step.kernel import rank_merge
+
+    @jax.jit
+    def top_k(pool_s, pool_i, pool_c, new_s, new_i, new_c):
+        s, sel = jax.lax.top_k(jnp.concatenate([pool_s, new_s], 1), l)
+        return (s,) + tuple(jnp.take_along_axis(jnp.concatenate(x, 1), sel, 1)
+                            for x in ((pool_i, new_i), (pool_c, new_c)))
+
+    merge = jax.jit(rank_merge)
+    for _ in range(10):
+        case = _merge_case(rng, 8, l, m, all_invalid)
+        for got, want in zip(merge(*case), top_k(*case)):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_beam_step_all_done_is_noop(rng):

@@ -83,8 +83,8 @@ def _walk_shapes(one_chip, quantized, has_live, ef=EF, m=M):
 @pytest.mark.parametrize(
     "quantized,has_live,ef,m",
     [(False, False, EF, M), (True, False, EF, M), (False, True, EF, M),
-     (False, False, 10, 10)],
-    ids=["f32", "int8", "live", "angular"],
+     (False, False, 10, 10), (False, False, 64, M)],
+    ids=["f32", "int8", "live", "angular", "find"],
 )
 def test_beam_step_compiles(one_chip, quantized, has_live, ef, m):
     from repro.kernels.beam_step.kernel import beam_step_pallas
