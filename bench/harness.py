@@ -2,19 +2,21 @@
 
 A cell (``bench/workloads/<cell>.json``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``); the mix names its arrival process
+(``bench/traffic/<traffic>.json``); the configuration names its index kind
+(``bench/indexes/<kind>.py``); the mix names its arrival process
 (``bench/traffic/<process>.py``); each metric is read by
 ``bench/metrics/<metric>.py``.  All are found by name, so a new cell,
-configuration, mix, arrival process or metric is a new file and a new
-entry, never an edit here.
+configuration, index kind, mix, arrival process or metric is a new file
+and a new entry, never an edit here.
 
 A run:
 
 1. Set-up (``setup_s``, from process start to the window's first instant):
-   the catalog from ``--seed`` on the host, the index built with the
-   configuration's parameters and the fused kernels, the bucket ladder and
-   ``ServeLoop`` with the repository's default ``LinearServiceModel``, the
-   ladder warmed, the window's requests made.
+   the catalog from ``--seed`` on the host, the index made and built by its
+   kind's module, the bucket ladder and ``ServeLoop`` with the repository's
+   default ``LinearServiceModel``, the ladder warmed, the window's requests
+   made.  A traced run then attaches a fresh ``MetricsRegistry`` to the
+   loop and its executor; an untraced run attaches none.
 2. The window: ``ServeLoop.run`` on a wall clock over open-loop arrivals
    due in ``[0, seconds)``.  Requests that finish after the window count.
 3. After it: the device's peak memory is read, the index is freed, and the
@@ -42,8 +44,8 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 class UnknownName(ValueError):
-    """A cell, configuration, traffic mix, arrival process or metric with
-    no entry or file."""
+    """A cell, configuration, index kind, traffic mix, arrival process or
+    metric with no entry or file."""
 
 
 # --------------------------------------------------------------------------
@@ -106,6 +108,14 @@ def reader(metric: str, root: pathlib.Path = ROOT) -> Callable:
                    f"reader for metric {metric!r}").read
 
 
+def index_kind(kind: str, root: pathlib.Path = ROOT):
+    """``bench/indexes/<kind>.py``: ``make(config) -> index`` and
+    ``build(index, items)``, which returns once the index's device arrays
+    are ready."""
+    return _module(root / "bench" / "indexes" / f"{kind}.py",
+                   f"index kind {kind!r}")
+
+
 def make_traffic(traffic: dict, cell: dict, seconds: float, seed: int,
                  dim: int, root: pathlib.Path = ROOT):
     """(queries [n, d], due times [n], per-request classes) of a window:
@@ -150,36 +160,13 @@ class Record:
     dispatches: List[Dispatch]
     trace: Optional[tracing.Reduced] = None
     peaks: Optional[dict] = None
+    # Each registry counter's growth over the window; empty untraced.
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 # --------------------------------------------------------------------------
 # The program under test
 # --------------------------------------------------------------------------
-
-
-def make_index(config: dict):
-    """The configuration's index, with the fused kernels of the chip path."""
-    from repro.core import IpNSW, IpNSWPlus
-
-    p = config["index"]
-    common = dict(
-        max_degree=p["max_degree"], ef_construction=p["ef_construction"],
-        insert_batch=p["insert_batch"], backend=p["backend"],
-        build_backend=p["build_backend"], commit_backend=p["commit_backend"],
-        storage=p["storage"],
-    )
-    if p["kind"] == "ipnsw_plus":
-        return IpNSWPlus(ang_degree=p["ang_degree"], ang_ef=p["ang_ef"],
-                         k_angular=p["k_angular"], **common)
-    if p["kind"] == "ipnsw":
-        return IpNSW(**common)
-    raise UnknownName(f"unknown index kind {p['kind']!r}")
-
-
-def _graph_arrays(index):
-    return [g.adj for g in (getattr(index, "graph", None),
-                            getattr(index, "ang_graph", None),
-                            getattr(index, "ip_graph", None)) if g is not None]
 
 
 def make_clock(annotate: bool):
@@ -295,22 +282,25 @@ class Server:
     clock: object
     dispatches: List[Dispatch]
     build_s: float
+    registry: Optional[object] = None   # a MetricsRegistry, traced runs
 
 
 def set_up(config: dict, cell: dict, seed: int, trace: bool,
-           log=print) -> Server:
-    """Catalog from the seed, index built, ladder warmed."""
+           log=print, root: pathlib.Path = ROOT) -> Server:
+    """Catalog from the seed, index built, ladder warmed; traced, a fresh
+    registry attached to the loop and its executor."""
     import jax
     from repro.launch import serve_loop as sl
+    from repro.obs import MetricsRegistry
 
+    kind = index_kind(config["index"]["kind"], root)
     cat = config["catalog"]
     items_np = yardstick.catalog(cat["n_items"], cat["dim"], cat["profile"],
                                  seed)
     log("catalog made")
-    index = make_index(config)
+    index = kind.make(config)
     t0 = time.perf_counter()
-    index.build(jax.device_put(items_np))
-    jax.block_until_ready(_graph_arrays(index))
+    kind.build(index, jax.device_put(items_np))
     build_s = time.perf_counter() - t0
     log(f"build_s={build_s:.3f}")
     ladder = sl.BucketLadder(batches=tuple(cell["ladder"]["batches"]),
@@ -320,17 +310,29 @@ def set_up(config: dict, cell: dict, seed: int, trace: bool,
                         service_model=sl.LinearServiceModel())
     loop.executor.warmup()
     log("ladder warm")
+    registry = None
+    if trace:
+        registry = loop.registry = loop.executor.registry = MetricsRegistry()
     dispatches = time_dispatches(loop.executor, clock, annotate=trace)
-    return Server(items_np, loop, clock, dispatches, build_s)
+    return Server(items_np, loop, clock, dispatches, build_s, registry)
+
+
+def _counters(registry) -> Dict[str, float]:
+    if registry is None:
+        return {}
+    return {m["name"]: m["value"] for m in registry.collect()
+            if m["kind"] == "counter"}
 
 
 def serve(server: Server, requests, trace_dir: Optional[str] = None):
     """The window: ``ServeLoop.run`` over the requests, traced into
-    ``trace_dir`` when given.  Returns the loop's ``ServeStats`` and the
-    number of programs compiled inside the window."""
+    ``trace_dir`` when given.  Returns the loop's ``ServeStats``, the
+    number of programs compiled inside the window, and each registry
+    counter's growth over it (empty without a registry)."""
     import jax
     from repro.launch import serve_loop as sl
 
+    before = _counters(server.registry)
     compiles = sl.xla_compile_events()
     if trace_dir:
         opts = jax.profiler.ProfileOptions()
@@ -342,7 +344,10 @@ def serve(server: Server, requests, trace_dir: Optional[str] = None):
         stats = server.loop.run(requests)
     if trace_dir:
         jax.profiler.stop_trace()
-    return stats, sl.xla_compile_events() - compiles
+    compiles = sl.xla_compile_events() - compiles
+    counters = {k: v - before.get(k, 0.0)
+                for k, v in _counters(server.registry).items()}
+    return stats, compiles, counters
 
 
 @dataclasses.dataclass
@@ -410,13 +415,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     info = _device_info(devices)
     k, dim = cell["k"], config["catalog"]["dim"]
 
-    server = set_up(config, cell, seed, trace, log)
+    server = set_up(config, cell, seed, trace, log, root)
     qs, arrivals, classes = make_traffic(traffic, cell, seconds, seed, dim,
                                          root)
     requests = make_requests(qs, arrivals, classes)
     log("window's requests made")
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
-    stats, steady_compiles = serve(server, requests, trace_dir)
+    stats, steady_compiles, counters = serve(server, requests, trace_dir)
     setup_s = server.clock.started_at - t_start
     info["memory_peak_bytes"] = peak_memory(devices)
     log(f"setup_s={setup_s:.3f} served={len(stats.responses)} "
@@ -451,7 +456,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                  finish_t=got.finish_t, recall=recall,
                  dispatches=dispatches, trace=reduced,
                  peaks=peaks(info["kind"], root)
-                 if info["platform"] == "tpu" else None)
+                 if info["platform"] == "tpu" else None, counters=counters)
     metrics = {}
     for m in metric_entries:
         v = reader(m["name"], root)(rec)

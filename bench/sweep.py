@@ -50,7 +50,7 @@ def main(argv=None) -> int:
             traffic, dict(cell, rate_qps=rate), args.seconds,
             args.seed + 1 + i, dim)
         del server.dispatches[:]
-        stats, compiles = harness.serve(
+        stats, compiles, _ = harness.serve(
             server, harness.make_requests(qs, t, classes))
         got = harness.answers(stats.responses, t.size, cell["k"])
         lat = got.finish_t - got.arrival_t

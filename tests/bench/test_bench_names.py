@@ -1,8 +1,10 @@
-"""The harness finds every cell, configuration, traffic mix, arrival
-process and metric of BENCHMARK.json by name, refuses an unknown one, and the file keeps to the
-shape later checks rely on."""
+"""The harness finds every cell, configuration, index kind, traffic mix,
+arrival process and metric of BENCHMARK.json by name, refuses an unknown
+one, and the file keeps to the shape later checks rely on."""
 import json
 import re
+import shutil
+import time
 
 import pytest
 
@@ -26,8 +28,9 @@ def test_every_cell_loads_with_its_configuration_and_mix(cell):
         kit.ROOT / "bench" / "traffic" / f"{traffic['process']}.py",
         "arrival process").make)
     assert 0 <= c["limits"]["miss_rate"] < 1 and c["limits"]["score_err"] > 0
-    assert harness.make_index(config).max_degree == \
-        config["index"]["max_degree"]
+    kind = harness.index_kind(config["index"]["kind"])
+    assert callable(kind.make) and callable(kind.build)
+    assert kind.make(config).max_degree == config["index"]["max_degree"]
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in METRICS])
@@ -50,6 +53,7 @@ def test_each_cell_reports_setup_another_end_to_end_and_a_layer(cell):
     ("metric", lambda: harness.reader("no_such_metric")),
     ("device", lambda: harness.peaks("TPU v0 imaginary")),
     ("metrics of", lambda: harness.metrics_for("no-such.cell", False)),
+    ("index kind", lambda: harness.index_kind("no_such_kind")),
 ])
 def test_unknown_names_are_refused(what, call):
     with pytest.raises(harness.UnknownName):
@@ -63,6 +67,52 @@ def test_an_unknown_configuration_or_mix_file_is_refused(tmp_path):
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
     with pytest.raises(harness.UnknownName):
         harness.load_cell("x.y", root=tmp_path)
+
+
+STUB_KIND = '''"""A kind written as a new file: ip-NSW under another name."""
+import jax
+
+
+def make(config):
+    from repro.core import IpNSW
+
+    p = config["index"]
+    return IpNSW(max_degree=p["max_degree"],
+                 ef_construction=p["ef_construction"],
+                 insert_batch=p["insert_batch"], backend=p["backend"])
+
+
+def build(index, items):
+    index.build(items)
+    jax.block_until_ready(index.graph.adj)
+'''
+
+
+def test_a_new_index_kind_is_a_new_file(tmp_path):
+    """A kind found under another root is made, built and served, with no
+    edit to the harness; a configuration naming a kind with no file is
+    refused before anything is built."""
+    (tmp_path / "bench" / "indexes").mkdir(parents=True)
+    (tmp_path / "bench" / "indexes" / "stub_kind.py").write_text(STUB_KIND)
+    shutil.copytree(kit.ROOT / "bench" / "traffic",
+                    tmp_path / "bench" / "traffic",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    cell, config, traffic = kit.small("yahoo-ipnsw.steady", n_items=400,
+                                      rate_qps=100.0)
+    config["index"]["kind"] = "stub_kind"
+
+    def run(root):
+        return harness.run_cell(None, 20250102, 1.0, False,
+                                t_start=time.perf_counter(), root=root,
+                                cell=cell, config=config, traffic=traffic,
+                                log=lambda m: None)
+
+    out = run(tmp_path)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] == 100 and out["failed"] == 0
+    assert out["record"].build_s > 0
+    with pytest.raises(harness.UnknownName):
+        run(kit.ROOT)
 
 
 def test_benchmark_file_keeps_the_contract_shape():
